@@ -13,6 +13,90 @@ import argparse
 import signal
 import sys
 import time
+from dataclasses import dataclass
+from typing import Any, Optional
+
+
+@dataclass
+class ServingNode:
+    """One running node: the native engine and TCP server, the optional
+    durable store, and the cluster control plane on top."""
+
+    engine: Any
+    server: Any
+    node: Any
+    storage: Optional[Any] = None
+    recovery: Optional[Any] = None
+
+    def close(self) -> None:
+        self.node.stop()
+        if self.storage is not None:
+            # After node.stop() (no more repair/replication writers), before
+            # the server/engine teardown: the final drain + shutdown
+            # snapshot still read through live handles.
+            self.storage.stop()
+        self.server.close()
+        self.engine.sync()
+        self.engine.close()
+
+
+def start_node(cfg) -> ServingNode:
+    """Start a node from a resolved ``Config``: engine, native server,
+    durable store when enabled, and the cluster control plane. The CLI
+    below and ``chip_smoke.py`` both start nodes through here."""
+    from merklekv_tpu.native_bindings import NativeEngine, NativeServer
+    from merklekv_tpu.version import __version__
+
+    engine = NativeEngine(cfg.engine, cfg.storage_path)
+
+    # Durable subsystem. The data dir is per-port (node_data_dir) so nodes
+    # sharing a cwd-relative storage_path — the multi-node test shape —
+    # cannot collide; the directory flock rejects whatever slips past that.
+    # On a FIXED port the dir is known up front, so recovery completes
+    # before the listening socket even exists — no window where a client
+    # reads pre-recovery state or writes an un-journaled key.
+    storage = recovery = None
+    if cfg.storage.enabled:
+        from merklekv_tpu.storage import DurableStore, node_data_dir
+
+        if cfg.port != 0:
+            storage = DurableStore(
+                engine, cfg.storage, node_data_dir(cfg.storage_path, cfg.port)
+            )
+            recovery = storage.recover()
+
+    server = NativeServer(
+        engine, cfg.host, cfg.port, version=__version__,
+        exit_on_shutdown=False, io_threads=cfg.server.io_threads,
+        reuseport=cfg.server.reuseport, zero_copy=cfg.server.zero_copy,
+        max_line=cfg.server.max_line_bytes,
+    )
+    if cfg.storage.enabled:
+        # BEFORE start(): stage change events from the very first accepted
+        # command — writes landing before the drain thread spins up wait in
+        # the native queue instead of silently bypassing the WAL.
+        server.enable_events(True)
+    server.start()
+
+    if cfg.storage.enabled:
+        if storage is None:
+            # port 0: the dir derives from the just-bound port; recovery
+            # still finishes before the readiness line harnesses gate on.
+            storage = DurableStore(
+                engine, cfg.storage, node_data_dir(cfg.storage_path, server.port)
+            )
+            recovery = storage.recover()
+        storage.start()
+
+    # Always wire the cluster control plane: the SYNC command must work on a
+    # bare node (reference parity — SyncManager is unconditional,
+    # server.rs:388-390); replication/anti-entropy loops only start when
+    # enabled in config.
+    from merklekv_tpu.cluster.node import ClusterNode
+
+    node = ClusterNode(cfg, engine, server, storage=storage)
+    node.start()
+    return ServingNode(engine, server, node, storage, recovery)
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -82,8 +166,6 @@ def main(argv: list[str] | None = None) -> int:
     args = p.parse_args(argv)
 
     from merklekv_tpu.config import load_or_default
-    from merklekv_tpu.native_bindings import NativeEngine, NativeServer
-    from merklekv_tpu.version import __version__
 
     # Join the multi-host jax cluster BEFORE any device touch when
     # MKV_COORDINATOR is set — the device data plane then runs over the
@@ -114,55 +196,8 @@ def main(argv: list[str] | None = None) -> int:
                     f"or a TCP port, got {args.metrics_port}")
         cfg.observability.http_port = args.metrics_port
 
-    engine = NativeEngine(cfg.engine, cfg.storage_path)
-
-    # Durable subsystem. The data dir is per-port (node_data_dir) so nodes
-    # sharing a cwd-relative storage_path — the multi-node test shape —
-    # cannot collide; the directory flock rejects whatever slips past that.
-    # On a FIXED port the dir is known up front, so recovery completes
-    # before the listening socket even exists — no window where a client
-    # reads pre-recovery state or writes an un-journaled key.
-    storage = None
-    if cfg.storage.enabled:
-        from merklekv_tpu.storage import DurableStore, node_data_dir
-
-        if cfg.port != 0:
-            storage = DurableStore(
-                engine, cfg.storage, node_data_dir(cfg.storage_path, cfg.port)
-            )
-            recovery = storage.recover()
-
-    server = NativeServer(
-        engine, cfg.host, cfg.port, version=__version__,
-        exit_on_shutdown=False, io_threads=cfg.server.io_threads,
-        reuseport=cfg.server.reuseport, zero_copy=cfg.server.zero_copy,
-        max_line=cfg.server.max_line_bytes,
-    )
-    if cfg.storage.enabled:
-        # BEFORE start(): stage change events from the very first accepted
-        # command — writes landing before the drain thread spins up wait in
-        # the native queue instead of silently bypassing the WAL.
-        server.enable_events(True)
-    server.start()
-
-    if cfg.storage.enabled:
-        if storage is None:
-            # port 0: the dir derives from the just-bound port; recovery
-            # still finishes before the readiness line harnesses gate on.
-            storage = DurableStore(
-                engine, cfg.storage, node_data_dir(cfg.storage_path, server.port)
-            )
-            recovery = storage.recover()
-        storage.start()
-
-    # Always wire the cluster control plane: the SYNC command must work on a
-    # bare node (reference parity — SyncManager is unconditional,
-    # server.rs:388-390); replication/anti-entropy loops only start when
-    # enabled in config.
-    from merklekv_tpu.cluster.node import ClusterNode
-
-    node = ClusterNode(cfg, engine, server, storage=storage)
-    node.start()
+    served = start_node(cfg)
+    node, server, storage = served.node, served.server, served.storage
 
     # Readiness line LAST: spawning harnesses treat it as "fully up",
     # including the replication subscription (QoS-0 — a publish before the
@@ -174,7 +209,7 @@ def main(argv: list[str] | None = None) -> int:
     )
     if storage is not None:
         # After the readiness line — spawning harnesses parse line 1 only.
-        print(f"storage: recovered {recovery.summary()}", flush=True)
+        print(f"storage: recovered {served.recovery.summary()}", flush=True)
     if node.metrics_port is not None:
         # After the readiness line, same rule; CI's exporter smoke job and
         # ops harnesses parse this to find an ephemeral metrics port.
@@ -193,16 +228,7 @@ def main(argv: list[str] | None = None) -> int:
         while not stop["flag"] and not server.stopping:
             time.sleep(0.1)
     finally:
-        if node is not None:
-            node.stop()
-        if storage is not None:
-            # After node.stop() (no more repair/replication writers), before
-            # the server/engine teardown: the final drain + shutdown
-            # snapshot still read through live handles.
-            storage.stop()
-        server.close()
-        engine.sync()
-        engine.close()
+        served.close()
     return 0
 
 

@@ -392,13 +392,10 @@ class DeviceTreeMirror:
         self._ensure_pump()  # a dead pump is respawned by fresh staging
         self._pump_wake.set()
 
-    def apply_one(self, key: bytes, value: Optional[bytes]) -> None:
-        """One remote write (anti-entropy repair hook)."""
-        self.apply_batch([(key, value)])
-
     def apply_batch(self, pairs: list[tuple[bytes, Optional[bytes]]]) -> None:
-        """Remote writes from one decoded replication frame: ONE lock
-        acquisition and ONE device-state staging call for the whole frame
+        """Remote writes from one decoded replication frame or one
+        anti-entropy repair page: ONE lock acquisition and ONE device-state
+        staging call for the whole batch
         (per-key applies paid both per event — at sustained remote write
         rates the lock/stage overhead, not the device math, dominated)."""
         if not pairs:
@@ -637,7 +634,7 @@ class DeviceTreeMirror:
           death): the state is not trustworthy — invalidate NOW (native
           fallback answers, a re-warm restores serving at the same rung).
           The ladder does not step: the rung isn't sick, the code is.
-        - ``environment`` (backend blip, hang, tunnel death): below the
+        - ``environment`` (backend blip, hang, lost device): below the
           degrade threshold the published tree stays — consistent, just
           stale; the flush restored its staged batch — and the next wake
           retries. At the threshold the ladder steps down and the mirror
@@ -1024,12 +1021,11 @@ class DeviceTreeMirror:
         cluster (parallel/multihost.py) jax.devices() includes other hosts'
         non-addressable chips, and a device_put onto those would fail or
         deadlock."""
-        # Honor MERKLEKV_JAX_PLATFORM before the first device use (not at
-        # module import): N spawned servers must not race for a
-        # single-process accelerator backend.
-        from merklekv_tpu.utils.jaxenv import ensure_platform
+        # Place the compile cache before the first device use (not at
+        # module import: a node without a mirror never imports jax).
+        from merklekv_tpu.utils.jaxenv import configure_compile_cache
 
-        ensure_platform()
+        configure_compile_cache()
         import jax
 
         from merklekv_tpu.parallel.sharded_state import resolve_shard_count

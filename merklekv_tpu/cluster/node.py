@@ -159,7 +159,7 @@ class ClusterNode:
         self.sync_manager = SyncManager(
             engine,
             device=cfg.anti_entropy.engine,
-            repair_listener=self._on_sync_repair,
+            repair_listener=self._on_sync_repairs,
             on_peer_degraded=self._on_peer_degraded,
             mode=cfg.anti_entropy.mode,
             bisect_threshold=cfg.anti_entropy.bisect_threshold,
@@ -832,14 +832,21 @@ class ClusterNode:
         if h is not None:
             h.mark_degraded(peer, reason)
 
-    def _on_sync_repair(self, key: bytes, value, ts=None) -> None:
+    def _on_sync_repairs(self, batch) -> None:
         """Anti-entropy repairs bypass the server event queue; feed the
         device mirror directly so HASH stays truthful after a SYNC, and the
-        WAL so a repaired key survives a crash without needing re-repair."""
+        WAL so a repaired key survives a crash without needing re-repair.
+        ``batch`` is one repair page: (key, value|None, ts|None) rows."""
         with self._rep_mu:
             mirror = self._mirror
         if mirror is not None:
-            mirror.apply_one(key, value)
+            # One staging call per page. Values are re-read from the
+            # engine, as for local events: a local write that landed after
+            # the repair has already staged its own (newer) value, and the
+            # repair's payload must not overwrite it in the mirror.
+            mirror.apply_batch(
+                [(key, self._engine.get(key)) for key, _, _ in batch]
+            )
         storage = self._storage
         if storage is not None:
             # Journal at the EXACT ts the repair installed (threaded through
@@ -849,12 +856,13 @@ class ClusterNode:
             # ordering metadata (delete_quiet absence copy, legacy full
             # transfer): skip the journal rather than fabricate a ts —
             # anti-entropy re-repairs after a crash.
-            if ts is None:
-                return
-            if value is None:
-                storage.record_delete(key, ts)
-            else:
-                storage.record_set(key, value, ts)
+            for key, value, ts in batch:
+                if ts is None:
+                    continue
+                if value is None:
+                    storage.record_delete(key, ts)
+                else:
+                    storage.record_set(key, value, ts)
 
     def _query_ready_mirror(self, fn, force: bool = False):
         """Shared gate for device-tree reads (HASH root, TREELEVEL slices):

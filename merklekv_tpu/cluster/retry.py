@@ -203,7 +203,7 @@ BOOTSTRAP_FETCH = RetryPolicy(
 
 # Device dispatch guard (merklekv_tpu.device.guard): ONE near-immediate
 # retry when a device program call fails with an environment-classified
-# error (backend RPC blip, transient tunnel reset) — a second failure
+# error (backend RPC blip, transient connection reset) — a second failure
 # escalates to the degradation ladder instead of retrying into a sick
 # backend. Hangs are never retried: the abandoned executor already spent
 # the dispatch deadline, and the pump's stall budget is the deadline, not

@@ -168,9 +168,7 @@ _SESSION_MAX_AGE_S = 600.0
 
 
 def _leaf_map_device(items: list[tuple[bytes, bytes]]) -> dict[bytes, bytes]:
-    from merklekv_tpu.utils.jaxenv import ensure_platform
-
-    ensure_platform()
+    jaxenv.configure_compile_cache()
     from merklekv_tpu.merkle.jax_engine import leaf_digests
     from merklekv_tpu.ops.sha256 import digests_to_bytes
 
@@ -214,12 +212,14 @@ class SyncManager:
         device: str = "auto",  # "auto" | "cpu" | "tpu"
         mget_batch: int = 512,
         timeout: Optional[float] = None,
-        # Callable[[bytes, Optional[bytes], Optional[int]], None]:
-        # (key, value|None, LWW ts|None). The ts is the EXACT timestamp
-        # the repair installed (peer write ts / tombstone ts), so a WAL
-        # can journal it without a racy engine read-back; None means the
-        # repair carried no ordering metadata (legacy full transfer,
-        # delete_quiet absence copy).
+        # Callable[[list[tuple[bytes, Optional[bytes], Optional[int]]]],
+        # None]: the repairs applied since the last call, as (key,
+        # value|None, LWW ts|None), handed over once per repair batch (a
+        # page or an mget batch) and at the end of every cycle. The ts is
+        # the EXACT timestamp the repair installed (peer write ts /
+        # tombstone ts), so a WAL can journal it without a racy engine
+        # read-back; None means the repair carried no ordering metadata
+        # (legacy full transfer, delete_quiet absence copy).
         repair_listener=None,
         retry: Optional[RetryPolicy] = None,
         on_peer_degraded: Optional[Callable[[str, str], None]] = None,
@@ -275,6 +275,11 @@ class SyncManager:
         # event queue — anything mirroring the keyspace (the device Merkle
         # tree) must be told explicitly or it serves stale roots forever.
         self._repair_listener = repair_listener
+        # Repairs applied but not yet handed to the listener. Batching them
+        # lets a warm device mirror stage one page per lock acquisition
+        # instead of one key, which its pump holds across device dispatch.
+        self._repairs: list = []
+        self._repairs_mu = threading.Lock()
         # Mid-sync failure hook: the peer is reported degraded (health.py
         # flips its table entry) while its checkpointed session waits.
         self._on_peer_degraded = on_peer_degraded
@@ -677,6 +682,7 @@ class SyncManager:
                         f"(peer {report.peer})"
                     )
         finally:
+            self._deliver_repairs()
             # Wire-byte accounting for the WHOLE cycle (probe, hash/tree
             # fetches, repairs, reconnects — the client counters survive
             # reconnects because the same client object re-dials).
@@ -1488,6 +1494,8 @@ class SyncManager:
                     self._repair_set(k, values[k], ts)
                     report.set_keys += 1
                 repaired += 1
+            self._deliver_repairs()
+        self._deliver_repairs()  # a page's deletions with no sets to batch
 
     # -- full path (reference behavior; --full or LEAFHASHES-less peer) -------
     def _sync_full(self, client: MerkleKVClient, report: SyncReport) -> None:
@@ -1527,22 +1535,35 @@ class SyncManager:
         get_metrics().inc("anti_entropy.skew_clamped")
         return limit
 
+    def _note_repair(
+        self, k: bytes, v: Optional[bytes], ts: Optional[int]
+    ) -> None:
+        if self._repair_listener is not None:
+            with self._repairs_mu:
+                self._repairs.append((k, v, ts))
+
+    def _deliver_repairs(self) -> None:
+        """Hand the repairs applied so far to the listener, in one call."""
+        with self._repairs_mu:
+            batch, self._repairs = self._repairs, []
+        if batch and self._repair_listener is not None:
+            self._repair_listener(batch)
+
     def _repair_set(self, k: bytes, v: bytes, ts: Optional[int] = None) -> None:
         ts = self._clamp_ts(ts)
         if ts is None:
             self._engine.set(k, v)
         else:
             self._engine.set_with_ts(k, v, ts)
-        if self._repair_listener is not None:
-            self._repair_listener(k, v, ts)
+        self._note_repair(k, v, ts)
 
     def _repair_set_lww(self, k: bytes, v: bytes, ts: int) -> bool:
         """Conditional install for multi-peer repair: a local write or
         deletion racing ahead of the fetched winner must not be clobbered."""
         ts = self._clamp_ts(ts)
         applied = self._engine.set_if_newer(k, v, ts)
-        if applied and self._repair_listener is not None:
-            self._repair_listener(k, v, ts)
+        if applied:
+            self._note_repair(k, v, ts)
         return applied
 
     def _repair_delete(self, k: bytes, tomb_ts: Optional[int] = None) -> None:
@@ -1560,8 +1581,7 @@ class SyncManager:
             self._engine.delete(k)  # engine doubles without ts-carrying ops
         else:
             self._engine.delete_with_ts(k, tomb_ts)
-        if self._repair_listener is not None:
-            self._repair_listener(k, None, tomb_ts)
+        self._note_repair(k, None, tomb_ts)
 
     def _repair_delete_lww(self, k: bytes, ts: int, was_present: bool) -> bool:
         """Conditional deletion for multi-peer repair (peer tombstone won).
@@ -1569,12 +1589,12 @@ class SyncManager:
         The listener fires on EVERY applied delete, not just when the
         start-of-cycle snapshot saw the key: a replication event may have
         installed it mid-cycle, and the device mirror must drop what the
-        engine just dropped (apply_one(k, None) is a no-op for absent
-        keys). ``was_present`` only scopes the report count."""
+        engine just dropped (staging the deletion of an absent key is a
+        no-op). ``was_present`` only scopes the report count."""
         ts = self._clamp_ts(ts)
         applied = self._engine.delete_if_newer(k, ts)
-        if applied and self._repair_listener is not None:
-            self._repair_listener(k, None, ts)
+        if applied:
+            self._note_repair(k, None, ts)
         return applied and was_present
 
     # -- multi-peer cycle -----------------------------------------------------
@@ -1810,9 +1830,7 @@ class SyncManager:
                 return report
             if use_device:
                 try:
-                    from merklekv_tpu.utils.jaxenv import ensure_platform
-
-                    ensure_platform()
+                    jaxenv.configure_compile_cache()
                     # Engine boundary: the N-replica comparison shards over
                     # the local device mesh when one exists and the union
                     # keyspace amortizes it (bit-identical masks).
@@ -1948,6 +1966,7 @@ class SyncManager:
                 if peer in self._sessions:  # deadline checkpoint, no raise
                     report.degraded.append(peer)
         finally:
+            self._deliver_repairs()
             for i, c in enumerate(clients):
                 if c is not None:
                     if traces is not None:
@@ -1979,9 +1998,7 @@ class SyncManager:
     ) -> list[bytes]:
         if use_device:
             try:
-                from merklekv_tpu.utils.jaxenv import ensure_platform
-
-                ensure_platform()
+                jaxenv.configure_compile_cache()
                 from merklekv_tpu.merkle.diff import diff_keys_pair
 
                 return diff_keys_pair(local_hashes, remote_hashes)

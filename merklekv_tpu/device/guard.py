@@ -5,15 +5,17 @@ scatter / restructure / level gathers / the sharded N-replica diff) routes
 through :func:`DispatchGuard.run` instead of touching jax directly:
 
 - the call executes on its **own daemon guard thread** with a
-  ``[device] dispatch_deadline_ms`` bound — a dispatch wedged inside a
-  backend RPC (MULTICHIP_r05's rc=124 shape, BENCH_r05's hung backend
-  init) is ABANDONED at the deadline (the thread is orphaned), so the
+  ``[device] dispatch_deadline_ms`` bound — a dispatch wedged inside the
+  runtime is ABANDONED at the deadline (the thread is orphaned), so the
   caller gets a typed :class:`DispatchHungError` instead of blocking
   forever, and concurrent dispatches never queue behind each other's
   deadlines;
+- :meth:`DispatchGuard.call` compiles a jitted program AHEAD of that
+  deadline (:meth:`DispatchGuard.compiled`), so a cold compile is never
+  read as a hang;
 - failures are classified by the shared environment|code table
   (``merklekv_tpu.utils.errorkind``): environment-classified errors
-  (transient tunnel reset, backend blip) retry ONCE under
+  (transient runtime or connection blip) retry ONCE under
   ``retry.DEVICE_DISPATCH`` backoff; code errors raise immediately;
 - everything that escapes wraps as :class:`DeviceDispatchError` carrying
   the classified ``kind`` — the degradation ladder's input signal.
@@ -24,8 +26,8 @@ guarded call — fail-Nth, persistent-until-heal, hang-past-deadline,
 corrupt-result — mirroring the WAL's ``WalErrnoInjector``. Spawned server
 processes pick injection up from the ``MKV_DEVICE_FAULTS`` env var (the
 process-level chaos hook for the CI device-chaos step). Nothing here
-imports jax; the guard is pure threading and costs one thread spawn per
-dispatch (~0.1 ms, small against the dispatch itself).
+imports jax at module load; the guard is pure threading and costs one
+thread spawn per dispatch (~0.1 ms, small against the dispatch itself).
 """
 
 from __future__ import annotations
@@ -70,7 +72,7 @@ class DeviceDispatchError(RuntimeError):
 
 class DispatchHungError(DeviceDispatchError):
     """A guarded dispatch blew through the deadline and was abandoned.
-    Always ``environment``: a hang is backend/tunnel weather, and the
+    Always ``environment``: a hang is runtime weather, and the
     wedged worker thread may still be inside the backend — the guard
     replaced it rather than wait."""
 
@@ -98,6 +100,7 @@ class DispatchGuard:
     ) -> None:
         self._deadline_ms = float(deadline_ms)
         self._policy = policy
+        self._executables: dict = {}  # compiled() cache, per signature
 
     @property
     def deadline_ms(self) -> float:
@@ -152,6 +155,46 @@ class DispatchGuard:
         if ok:
             return out
         raise out
+
+    def compiled(self, label: str, program, *args):
+        """The executable of the jitted ``program`` for ``args``' signature
+        (shapes, dtypes, shardings), compiled on the CALLER's thread outside
+        the deadline and cached. A cold compile of a full-size program takes
+        seconds to tens of seconds (a 2^20-leaf tree build compiles in ~20 s
+        for v5e); the deadline then bounds only execution, so a first
+        compile is never read as a hang. A compile error raises a
+        ``DeviceDispatchError`` classified like any dispatch failure."""
+        import jax
+
+        leaves, tree = jax.tree_util.tree_flatten(args)
+        key = (program, tree, tuple(
+            (x.shape, str(x.dtype), getattr(x, "sharding", None))
+            for x in leaves
+        ))
+        exe = self._executables.get(key)
+        if exe is None:
+            try:
+                exe = program.lower(*args).compile()
+            except Exception as e:
+                get_metrics().inc("device.guard_errors")
+                raise DeviceDispatchError(
+                    label, classify_exception(e),
+                    f"compile: {type(e).__name__}: {e}",
+                ) from e
+            self._executables[key] = exe
+        return exe
+
+    def call(self, label: str, program, *args, fetch: bool = False):
+        """Run the jitted ``program`` on ``args`` under the guard, compiled
+        ahead of the deadline (:meth:`compiled`). The result stays on the
+        device (dispatch is asynchronous); ``fetch`` brings it to the host
+        inside the deadline instead, so a wedged execution is caught."""
+        import jax
+
+        exe = self.compiled(label, program, *args)
+        if fetch:
+            return self.run(label, lambda: jax.device_get(exe(*args)))
+        return self.run(label, lambda: exe(*args))
 
     def run(self, label: str, fn: Callable[[], T]) -> T:
         """Run one device program call under the guard. Returns ``fn()``'s

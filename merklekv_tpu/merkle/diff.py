@@ -151,7 +151,7 @@ def divergence_masks_engine(
     if mesh is None:
         return divergence_masks(digests, present)
     from merklekv_tpu.device.guard import DeviceDispatchError, get_guard
-    from merklekv_tpu.parallel.sharded_merkle import sharded_divergence
+    from merklekv_tpu.parallel.sharded_merkle import divergence_program
 
     d = int(mesh.shape["key"])
     pad = (-n) % d
@@ -172,8 +172,9 @@ def divergence_masks_engine(
         # anti-entropy walk. Label follows the documented shard{N}_*
         # scheme so chaos globs targeting the sharded rungs (shard*,
         # shard8_*) reach this seam too.
-        masks, _counts = get_guard().run(
-            f"shard{d}_diff", lambda: sharded_divergence(mesh, dig, pres)
+        masks, _counts = get_guard().call(
+            f"shard{d}_diff", divergence_program(mesh, "key"),
+            jnp.asarray(dig), jnp.asarray(pres),
         )
     except DeviceDispatchError:
         # The sharded program is an optimization, never the contract: the

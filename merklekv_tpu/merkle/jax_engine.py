@@ -21,7 +21,7 @@ Two build paths:
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional, Sequence
 
 import numpy as np
@@ -29,7 +29,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from merklekv_tpu.merkle.packing import pack_leaves
+from merklekv_tpu.merkle.packing import pack_leaf_groups
 from merklekv_tpu.ops.sha256 import (
     digest_to_bytes,
     digests_to_bytes,
@@ -89,20 +89,51 @@ def _leaf_digests_jit(blocks: jax.Array, nblocks: jax.Array) -> jax.Array:
     return sha256_blocks(blocks, nblocks)
 
 
-def leaf_digests(keys: Sequence[bytes], values: Sequence[bytes]) -> jax.Array:
-    """[N, 8] uint32 leaf digests for N (key, value) pairs, hashed on device.
+@lru_cache(maxsize=None)
+def _leaf_fn(pallas: bool):
+    """The jitted leaf-hash program for the dispatch backend."""
+    from merklekv_tpu.ops.dispatch import hash_blocks
 
-    Backend-dispatched: Pallas kernels on TPU (ops/dispatch.py), the scan
-    formulation elsewhere — so every caller (mirror warm build, incremental
-    tree, sync leaf maps) gets the tuned production path on the chip."""
-    from merklekv_tpu.ops.dispatch import hash_blocks, use_pallas
+    return jax.jit(hash_blocks) if pallas else _leaf_digests_jit
 
-    packed = pack_leaves(list(keys), list(values))
-    if packed.n == 0:
-        return jnp.zeros((0, 8), jnp.uint32)
-    if use_pallas():
-        return hash_blocks(packed.blocks, packed.nblocks)
-    return _leaf_digests_jit(packed.blocks, packed.nblocks)
+
+def leaf_digests(
+    keys: Sequence[bytes],
+    values: Sequence[bytes],
+    label: Optional[str] = None,
+) -> np.ndarray:
+    """[N, 8] uint32 leaf digests (host array, input order) for N (key,
+    value) pairs, hashed on device.
+
+    The pairs are hashed in size-bucketed groups (``pack_leaf_groups``),
+    one device call per group, so a few large values never pad the whole
+    batch. Calls are dispatched asynchronously, so the device hashes one
+    group while the host packs the next; the digests come back in one
+    fetch at the end. Backend-dispatched: Pallas kernels on TPU
+    (ops/dispatch.py), the scan formulation elsewhere — every caller
+    (mirror warm build, incremental tree, sync leaf maps) gets the
+    production path on the chip. With ``label``, each call and the fetch
+    run under the dispatch guard with that label (programs compiled ahead
+    of its deadline); host packing stays outside it."""
+    from merklekv_tpu.device.guard import get_guard
+    from merklekv_tpu.ops.dispatch import use_pallas
+
+    keys, values = list(keys), list(values)
+    program = _leaf_fn(use_pallas())
+    guard = get_guard()
+    parts = []
+    for rows, packed in pack_leaf_groups(keys, values):
+        args = (jnp.asarray(packed.blocks), jnp.asarray(packed.nblocks))
+        digests = guard.call(label, program, *args) if label else program(*args)
+        parts.append((rows, digests))
+
+    def fetch() -> np.ndarray:
+        out = np.zeros((len(keys), 8), np.uint32)
+        for rows, digests in parts:
+            out[rows] = np.asarray(digests)[: len(rows)]
+        return out
+
+    return guard.run(label, fetch) if label else fetch()
 
 
 # ------------------------------------------------------------ static build

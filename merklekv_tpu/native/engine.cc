@@ -150,6 +150,7 @@ void MemEngine::install_locked(Shard& s, const std::string& key,
     acct((long long)key.size());
     slab_->engine_hold(nsz);
     s.map.emplace(key, Entry{std::move(block), ts});
+    s.order.insert(key);  // no-op when a tombstone already indexed it
   }
   // A present value supersedes any deletion record: without this a key
   // would be advertised live AND tombstoned to peers at once.
@@ -161,6 +162,8 @@ bool MemEngine::erase_locked(Shard& s, const std::string& key) {
   if (it == s.map.end()) return false;
   acct(-(long long)key.size());
   slab_->engine_hold(-(long long)it->second.value.size());
+  // Before the map erase: `key` may alias the node's own key.
+  if (!s.tombs.count(key)) s.order.erase(key);
   s.map.erase(it);  // drops the engine ref; in-flight responses keep theirs
   return true;
 }
@@ -235,6 +238,7 @@ std::optional<std::pair<std::string, uint64_t>> MemEngine::get_with_ts(
 bool MemEngine::note_tomb(Shard& s, const std::string& key, uint64_t ts) {
   // Caller holds the shard's unique lock.
   auto [it, inserted] = s.tombs.try_emplace(key, ts);
+  if (inserted) s.order.insert(key);
   bool advanced = inserted;
   if (!inserted && it->second < ts) {
     it->second = ts;
@@ -267,6 +271,7 @@ bool MemEngine::note_tomb(Shard& s, const std::string& key, uint64_t ts) {
         // evicted: set_if_newer uses it as a conservative floor so an
         // evicted deletion still blocks stale resurrection.
         if (i->second > s.tomb_evict_hwm) s.tomb_evict_hwm = i->second;
+        if (!s.map.count(i->first)) s.order.erase(i->first);
         i = s.tombs.erase(i);
         ++evicted;
       } else {
@@ -498,45 +503,33 @@ std::vector<std::pair<std::string, bool>> Engine::page_between(
 
 std::vector<std::pair<std::string, bool>> MemEngine::page_between(
     const std::string& after, const std::string* upto, size_t limit) {
-  // Bounded top-k selection: the `limit` smallest keys strictly after the
-  // cursor via a max-heap, O(N log limit) per page with no full-keyspace
-  // vector or sort — a paged anti-entropy walk over N keys costs
-  // O(N^2/page * log page) comparisons instead of O(N^2/page * log N)
-  // plus a whole-keyspace copy per page. Within a shard the live map and
-  // tombstone map are disjoint (a set erases its tombstone under the same
-  // lock), and both are read under one shared_lock here, so no key can
-  // appear twice and the page never comes up short while keys remain.
-  // An exclusive `upto` bound drops out-of-range keys at offer time, so a
+  // Seek each shard's sorted key index to the cursor and take at most
+  // `limit` keys from it, then keep the `limit` smallest of the merged
+  // candidates: O(page * shards) per page whatever the keyspace size, so
+  // a paged anti-entropy walk over N keys costs O(N), not O(N^2/page).
+  // A key lives in one shard, and within it in exactly one of the live
+  // and tombstone maps (a set erases its tombstone under the same lock),
+  // so no key appears twice and the page never comes up short while keys
+  // remain. The exclusive `upto` bound stops each shard's run, so a
   // range-bounded page (the bisection walk's leaf fetch) never selects —
   // let alone ships — anything past the divergent range.
   using Row = std::pair<std::string, bool>;  // (key, is_tombstone)
-  auto by_key = [](const Row& a, const Row& b) { return a.first < b.first; };
-  std::vector<Row> heap;
-  heap.reserve(limit + 1);
-  auto offer = [&](const std::string& k, bool tomb) {
-    if (k <= after) return;
-    if (upto && k >= *upto) return;
-    if (heap.size() == limit && heap.front().first <= k) return;
-    heap.emplace_back(k, tomb);
-    std::push_heap(heap.begin(), heap.end(), by_key);
-    if (heap.size() > limit) {
-      std::pop_heap(heap.begin(), heap.end(), by_key);
-      heap.pop_back();
-    }
-  };
+  std::vector<Row> rows;
   for (Shard& s : shards_) {
     std::shared_lock lk(s.mu);
-    for (const auto& [k, e] : s.map) {
-      (void)e;
-      offer(k, false);
-    }
-    for (const auto& [k, ts] : s.tombs) {
-      (void)ts;
-      offer(k, true);
+    size_t taken = 0;
+    for (auto it = s.order.upper_bound(after);
+         it != s.order.end() && taken < limit; ++it, ++taken) {
+      if (upto && *it >= *upto) break;
+      rows.emplace_back(*it, s.map.count(*it) == 0);
     }
   }
-  std::sort_heap(heap.begin(), heap.end(), by_key);
-  return heap;
+  const size_t n = std::min(limit, rows.size());
+  std::partial_sort(
+      rows.begin(), rows.begin() + ptrdiff_t(n), rows.end(),
+      [](const Row& a, const Row& b) { return a.first < b.first; });
+  rows.resize(n);
+  return rows;
 }
 
 size_t MemEngine::dbsize() {
@@ -660,6 +653,7 @@ bool MemEngine::truncate() {
     // TRUNCATE is a local admin wipe, not a per-key deletion: it stays
     // local (never replicated) and drops deletion history with the data.
     s.tombs.clear();
+    s.order.clear();
     s.tomb_evict_hwm = 0;  // the wipe erases deletion knowledge by intent
   }
   bump_version();

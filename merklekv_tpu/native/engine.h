@@ -16,6 +16,7 @@
 #include <cstdint>
 #include <memory>
 #include <optional>
+#include <set>
 #include <shared_mutex>
 #include <string>
 #include <string_view>
@@ -319,8 +320,8 @@ class Engine {
   // HASHPAGE unit of resumable anti-entropy. Fewer rows than `limit`
   // means the keyspace past the cursor is exhausted, so implementations
   // must not drop rows mid-page. Base implementation pages over
-  // scan()+tombstones(); MemEngine overrides with a bounded top-k
-  // selection so a paged walk does not sort the whole keyspace per page.
+  // scan()+tombstones(); MemEngine overrides with a seek into its sorted
+  // per-shard key index, so a page costs O(page), not O(keyspace).
   std::vector<std::pair<std::string, bool>> page_after(
       const std::string& after, size_t limit) {
     return page_between(after, nullptr, limit);
@@ -443,6 +444,12 @@ class MemEngine : public Engine {
     // also lose LWW repair on this shard (they remain repairable through
     // pairwise mirror sync, which is unconditional).
     uint64_t tomb_evict_hwm = 0;
+    // Sorted index of every key in `map` or `tombs` (they are disjoint),
+    // kept in step at each insert/erase of either. It makes a HASHPAGE
+    // page O(page * shards) instead of a scan of the shard: a paged walk
+    // over the whole keyspace is then linear in it, not quadratic. Costs
+    // one more copy of each key (~64 B per key in a std::set node).
+    std::set<std::string> order;
   };
   // Records the deletion; returns whether the tombstone advanced (new, or
   // moved to a later ts). Caller holds the shard's unique lock.

@@ -1180,11 +1180,13 @@ void Server::run_command(const std::string& line,
             std::chrono::steady_clock::now() - t0)
             .count());
     if (timed) stats_.latency.observe_ns(dur_ns);
-    if (slow_us > 0 && dur_ns / 1000 >= slow_us) {
+    // Whole microseconds, rounded up: a sub-microsecond command still
+    // counts as 1 us, so the smallest threshold (1) logs every command.
+    const uint64_t dur_us = (dur_ns + 999) / 1000;
+    if (slow_us > 0 && dur_us >= slow_us) {
       // Record verb/latency/connection in the native flight log, and
       // relay to the control plane (when attached) so the Python flight
       // ring carries the same record on the node's merged timeline.
-      const uint64_t dur_us = dur_ns / 1000;
       const char* vn = verb_name(parsed.cmd.verb);
       flight_.record(vn, meta->addr, unix_now_ns() - dur_ns, dur_us);
       ClusterCallback cb;
@@ -1900,10 +1902,10 @@ void Server::dispatch(const Command& cmd, OutQueue& out, bool* close_conn) {
       const int64_t want = cmd.amount.value_or(1);
       // Stamp read before the page selection (live engine, lag 0).
       uint64_t page_ver = engine_->version();
-      // page_between is the engine's bounded top-k selection: O(N log page)
-      // per request instead of materializing + sorting the whole keyspace
-      // for every page of the walk (which made one full paged walk
-      // O(N^2/page) — ruinous at the 10M-key target). The optional
+      // page_between seeks the engine's sorted key index: O(page) per
+      // request instead of a pass over the whole keyspace for every page
+      // of the walk (which made one full paged walk O(N^2/page) —
+      // ruinous at the 10M-key target). The optional
       // exclusive upper bound serves the bisection walk's range-bounded
       // leaf fetch: nothing past the divergent range is selected or sent.
       const std::string* upto = cmd.upto ? &*cmd.upto : nullptr;

@@ -27,6 +27,7 @@ from typing import Callable, Optional
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libmerklekv.so")
 _SERVER_BIN = os.path.join(_NATIVE_DIR, "merklekv-server")
+_BUILD_LOCK = os.path.join(_NATIVE_DIR, ".build.lock")
 
 _CLUSTER_CB = ctypes.CFUNCTYPE(
     ctypes.c_int,
@@ -39,22 +40,43 @@ _CLUSTER_CB = ctypes.CFUNCTYPE(
 _lib: Optional[ctypes.CDLL] = None
 
 
-def ensure_built() -> None:
-    """Build the native library if missing or stale (any source newer)."""
+def _built() -> bool:
+    """Library and server binary exist and no source is newer."""
     srcs = [
         os.path.join(_NATIVE_DIR, f)
         for f in os.listdir(_NATIVE_DIR)
         if f.endswith((".cc", ".h", "Makefile"))
     ]
-    if os.path.exists(_LIB_PATH):
-        lib_mtime = os.path.getmtime(_LIB_PATH)
-        if all(os.path.getmtime(s) <= lib_mtime for s in srcs):
-            return
-    subprocess.run(
-        ["make", "-C", _NATIVE_DIR, "-j", str(os.cpu_count() or 2)],
-        check=True,
-        capture_output=True,
+    newest = max(os.path.getmtime(s) for s in srcs)
+    return all(
+        os.path.exists(p) and os.path.getmtime(p) >= newest
+        for p in (_LIB_PATH, _SERVER_BIN)
     )
+
+
+def ensure_built(force: bool = False) -> None:
+    """Build the native library and server if missing or stale (any source
+    newer); ``force`` rebuilds every object from the sources whatever the
+    tree holds. Concurrent callers (test workers, spawned nodes) serialize
+    on an flock and re-check staleness under it, so one make runs and the
+    rest load its result."""
+    import fcntl
+
+    if not force and _built():
+        return
+    with open(_BUILD_LOCK, "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not force and _built():
+            return
+        cmd = ["make", "-C", _NATIVE_DIR, "-j", str(os.cpu_count() or 2)]
+        out = subprocess.run(
+            cmd + (["-B"] if force else []), capture_output=True, text=True
+        )
+        if out.returncode != 0:
+            raise RuntimeError(
+                f"native build failed (rc={out.returncode}): "
+                f"{out.stderr[-2000:]}"
+            )
 
 
 def server_binary() -> str:

@@ -335,7 +335,7 @@ def find_anomalies(
             and str(f.get("want", "")).isdigit()
             and int(f["have"]) < int(f["want"])
         ):
-            # Device-complement shortfall (MULTICHIP_r01's failure mode):
+            # Device-complement shortfall ("need 8 devices, have 1"):
             # surfaced as ENVIRONMENT weather so triage reads the cause
             # directly instead of treating the round as a code regression
             # (the probe's JSON record carries the matching error_kind).

@@ -186,6 +186,9 @@ CATALOG: dict[str, tuple[str, str]] = {
         "counter", "Guarded device dispatches abandoned at the [device] "
         "dispatch_deadline_ms bound (the wedged worker is orphaned; the "
         "caller gets a typed hang error)."),
+    "device.pallas_interpreted": (
+        "counter", "Pallas SHA-256 kernels traced in interpret mode (the "
+        "CPU/test path; 0 on a chip, where every kernel runs compiled)."),
     "device.guard_retries": (
         "counter", "Guarded device dispatches retried once after an "
         "environment-classified failure (transient backend blip)."),

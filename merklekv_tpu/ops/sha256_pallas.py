@@ -15,7 +15,9 @@ that XLA fuses into the surrounding program.
 
 Kernels:
 - ``leaf_digests_pallas``: variable-block-count messages with per-message
-  valid-block masking (same contract as ``sha256_blocks``).
+  valid-block masking (same contract as ``sha256_blocks``). The block axis
+  is a second, sequential grid dimension with the state in VMEM scratch,
+  so VMEM use and compile time do not grow with message length.
 - ``node_pairs_pallas``: Merkle inner nodes — two-digest message, second
   compression on the constant padding block.
 - ``tree_root_pallas``: bottom-up tree build; Pallas for the wide levels,
@@ -36,6 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from merklekv_tpu.obs.metrics import get_metrics
 from merklekv_tpu.ops.sha256 import _IV, _K, _NODE_PAD_BLOCK, sha256_node_pairs
 
 __all__ = [
@@ -68,9 +71,11 @@ def pallas_supported() -> bool:
 
 
 def _interpret(interpret) -> bool:
-    if interpret is None:
-        return not pallas_supported()
-    return bool(interpret)
+    out = not pallas_supported() if interpret is None else bool(interpret)
+    if out:
+        # Counted where a kernel is traced: on a chip this must stay 0.
+        get_metrics().inc("device.pallas_interpreted")
+    return out
 
 
 # ------------------------------------------------------------ kernel math
@@ -167,22 +172,67 @@ def _compress_tiles_const(state: list, kw64: list[int]) -> list:
 
 # ------------------------------------------------------------ leaf kernel
 
-def _leaf_kernel(blocks_ref, nblocks_ref, out_ref):
-    """blocks_ref [1, B, 16, S, L] u32; nblocks_ref [1, S, L] i32;
-    out_ref [1, 8, S, L] u32."""
-    n_blocks = blocks_ref.shape[1]
-    shape = (blocks_ref.shape[3], blocks_ref.shape[4])
-    state = _iv_tiles(shape)
-    nb = nblocks_ref[0]
-    for b in range(n_blocks):
-        words = [blocks_ref[0, b, i] for i in range(16)]
-        new_state = _compress_tiles(state, words)
-        # Mask unconditionally so lanes padded with nblocks == 0 really do
-        # keep the IV — callers may rely on that invariant.
-        keep = nb > b
-        state = [jnp.where(keep, n, s) for n, s in zip(new_state, state)]
+# Blocks one leaf-kernel call walks. A longer message runs as a scan of
+# such calls with the state carried between them, so the word-planes one
+# call materializes (TILE_M lanes x this many blocks, 64 MiB) stay bounded
+# whatever the value size — a lone 1 MiB value still pads to TILE_M lanes.
+LEAF_CHUNK_BLOCKS = 512
+
+
+def _leaf_kernel(state_in_ref, blocks_ref, nblocks_ref, out_ref, state_ref):
+    """Grid (tile, block). state_in_ref [1, 8, S, L]; blocks_ref
+    [1, 1, 16, S, L] is block j of the tile's messages; nblocks_ref
+    [1, S, L] i32 valid blocks per lane; out_ref [1, 8, S, L].
+
+    The 8-word state lives in the VMEM scratch ``state_ref`` [8, S, L]
+    across the block axis, so VMEM use and the traced program are one
+    compression whatever the message length. Lanes whose message is
+    shorter than j (including padding lanes with nblocks == 0) keep their
+    state; the digest is written once, at the last block."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        state_ref[...] = state_in_ref[0]
+
+    state = [state_ref[i] for i in range(8)]
+    new_state = _compress_tiles(state, [blocks_ref[0, 0, w] for w in range(16)])
+    keep = nblocks_ref[0] > j
     for i in range(8):
-        out_ref[0, i] = state[i]
+        state_ref[i] = jnp.where(keep, new_state[i], state[i])
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        out_ref[0] = state_ref[...]
+
+
+def _leaf_call(state, planes, nb, interpret):
+    """One kernel call: state [G, 8, S, L] through the blocks of planes
+    [G, B, 16, S, L], masked by nb [G, S, L] -> [G, 8, S, L]."""
+    g, n_blk = planes.shape[0], planes.shape[1]
+    tile = lambda i, j: (i, 0, 0, 0)  # noqa: E731
+    return pl.pallas_call(
+        _leaf_kernel,
+        grid=(g, n_blk),
+        in_specs=[
+            pl.BlockSpec((1, 8, TILE_S, TILE_L), tile,
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, 16, TILE_S, TILE_L),
+                         lambda i, j: (i, j, 0, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, TILE_S, TILE_L), lambda i, j: (i, 0, 0),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((1, 8, TILE_S, TILE_L), tile,
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((g, 8, TILE_S, TILE_L), jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((8, TILE_S, TILE_L), jnp.uint32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
+        interpret=interpret,
+        name="sha256_leaf",
+    )(state, planes, nb)
 
 
 def _to_planes(rows: jax.Array) -> jax.Array:
@@ -204,35 +254,30 @@ def _leaf_digests_impl(blocks, nblocks, interpret):
     n, n_blk = blocks.shape[0], blocks.shape[1]
     m = ((n + TILE_M - 1) // TILE_M) * TILE_M
     g = m // TILE_M
-    blocks = jnp.pad(blocks.astype(jnp.uint32), ((0, m - n), (0, 0), (0, 0)))
+
+    def planes_of(rows):  # [n, B, 16] -> [G, B, 16, S, L], rows padded
+        b = rows.shape[1]
+        rows = jnp.pad(rows.astype(jnp.uint32), ((0, m - n), (0, 0), (0, 0)))
+        return rows.reshape(g, TILE_S, TILE_L, b, 16).transpose(0, 3, 4, 1, 2)
+
     # pad nblocks with 0 so padded lanes keep the IV (never compressed)
     nb = jnp.pad(nblocks.astype(jnp.int32), (0, m - n))
-    blocks_planes = (
-        blocks.reshape(g, TILE_S, TILE_L, n_blk, 16).transpose(0, 3, 4, 1, 2)
-    )  # [G, B, 16, S, L]
-    nb_planes = nb.reshape(g, TILE_S, TILE_L)
+    nb = nb.reshape(g, TILE_S, TILE_L)
+    state = jnp.broadcast_to(
+        jnp.asarray(_IV, jnp.uint32)[None, :, None, None],
+        (g, 8, TILE_S, TILE_L),
+    )
+    # Chunks of at most LEAF_CHUNK_BLOCKS blocks, the state carried from
+    # one kernel call to the next (a single trip for short messages).
+    c = min(n_blk, LEAF_CHUNK_BLOCKS)
+    k = -(-n_blk // c)
+    blocks = jnp.pad(blocks, ((0, 0), (0, k * c - n_blk), (0, 0)))
 
-    out = pl.pallas_call(
-        _leaf_kernel,
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec(
-                (1, n_blk, 16, TILE_S, TILE_L),
-                lambda i: (i, 0, 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, TILE_S, TILE_L), lambda i: (i, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 8, TILE_S, TILE_L), lambda i: (i, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        out_shape=jax.ShapeDtypeStruct((g, 8, TILE_S, TILE_L), jnp.uint32),
-        interpret=_interpret(interpret),
-    )(blocks_planes, nb_planes)
+    def step(i, st):
+        chunk = jax.lax.dynamic_slice_in_dim(blocks, i * c, c, axis=1)
+        return _leaf_call(st, planes_of(chunk), nb - i * c, interpret)
+
+    out = jax.lax.fori_loop(0, k, step, state)
     return _from_planes(out)[:n]
 
 

@@ -22,20 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map_impl
-
-    _REPLICATION_CHECK_KW = "check_vma"
-except ImportError:  # older jax: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _REPLICATION_CHECK_KW = "check_rep"
-
-
-def shard_map(*args, **kwargs):
-    if "check_vma" in kwargs and _REPLICATION_CHECK_KW != "check_vma":
-        kwargs[_REPLICATION_CHECK_KW] = kwargs.pop("check_vma")
-    return _shard_map_impl(*args, **kwargs)
+from jax import shard_map
 
 from merklekv_tpu.merkle.diff import divergence_masks, divergence_vs_ref
 from merklekv_tpu.ops.dispatch import build_levels, hash_blocks, use_pallas
@@ -43,6 +30,7 @@ from merklekv_tpu.ops.dispatch import build_levels, hash_blocks, use_pallas
 __all__ = [
     "sharded_tree_root",
     "sharded_divergence",
+    "divergence_program",
     "sharded_divergence_2d",
     "sharded_anti_entropy_step",
     "make_anti_entropy_step",
@@ -125,11 +113,13 @@ def sharded_divergence(
     """
     if digests.shape[1] % mesh.shape[axis]:
         raise ValueError("key axis not divisible by mesh")
-    return _divergence_program(mesh, axis)(digests, present)
+    return divergence_program(mesh, axis)(digests, present)
 
 
 @lru_cache(maxsize=None)
-def _divergence_program(mesh: Mesh, axis: str):
+def divergence_program(mesh: Mesh, axis: str):
+    """The jitted program behind :func:`sharded_divergence`, one per
+    (mesh, axis): (digests, present) -> (masks, counts)."""
     @partial(
         shard_map,
         mesh=mesh,
@@ -372,12 +362,12 @@ def sharded_levels_program(mesh: Mesh, axis: str, capacity: int, pallas: bool):
 
 @lru_cache(maxsize=None)
 def sharded_scatter_program(
-    mesh: Mesh, axis: str, capacity: int, kb: int, nblk: int, pallas: bool
+    mesh: Mesh, axis: str, capacity: int, kb: int, pallas: bool
 ):
-    """Fused per-shard-routed incremental update: ONE SPMD program hashes
-    each shard's routed sub-batch, scatters it into the shard-local leaf
-    slice, re-reduces only the touched parent paths, and rebuilds the tiny
-    top tree from the all_gathered shard roots.
+    """Per-shard-routed incremental update: ONE SPMD program scatters each
+    shard's routed sub-batch of fresh leaf digests into the shard-local
+    leaf slice, re-reduces only the touched parent paths, and rebuilds the
+    tiny top tree from the all_gathered shard roots.
 
     Inputs are ROUTED host-side ([D, kb, ...] arrays sharded on dim 0, so
     each device receives only its own sub-batch): ``idx`` holds SHARD-LOCAL
@@ -397,19 +387,17 @@ def sharded_scatter_program(
         mesh=mesh,
         in_specs=(
             *specs[:n_local],
-            P(axis, None),              # idx      [D, kb]
-            P(axis, None, None, None),  # blocks   [D, kb, nblk, 16]
-            P(axis, None),              # nblocks  [D, kb]
+            P(axis, None),        # idx      [D, kb]
+            P(axis, None, None),  # digests  [D, kb, 8]
         ),
         out_specs=specs,
         check_vma=False,
     )
     def go(*args):
-        levels, (idx, blocks, nblocks) = args[:n_local], args[n_local:]
-        from merklekv_tpu.ops.dispatch import hash_blocks as _hash
+        levels, (idx, digests) = args[:n_local], args[n_local:]
         from merklekv_tpu.ops.dispatch import hash_node_pairs as _pairs
 
-        new = _hash(blocks[0], nblocks[0])  # [kb, 8]
+        new = digests[0]  # [kb, 8]
         tgt = idx[0]  # [kb] local positions; pads already == scratch (L)
         scratch = jnp.zeros((1, 8), jnp.uint32)
         child = jnp.concatenate([levels[0], scratch]).at[tgt].set(new)

@@ -38,6 +38,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from merklekv_tpu.device.guard import get_guard
 from merklekv_tpu.merkle.incremental import DeviceMerkleState, _bucket
+from merklekv_tpu.merkle.jax_engine import leaf_digests
 from merklekv_tpu.obs.metrics import get_metrics
 from merklekv_tpu.ops.dispatch import use_pallas
 from merklekv_tpu.parallel.mesh import make_mesh
@@ -184,9 +185,7 @@ class ShardedDeviceMerkleState(DeviceMerkleState):
             self._mesh, self._axis, len(padded), use_pallas()
         )
         t0 = time.perf_counter()
-        levels = get_guard().run(
-            self._label("build"), lambda: fn(self._put(padded))
-        )
+        levels = get_guard().call(self._label("build"), fn, self._put(padded))
         self._record_rebuild(t0)
         return levels
 
@@ -197,67 +196,53 @@ class ShardedDeviceMerkleState(DeviceMerkleState):
             self._mesh, self._axis, self._capacity, c_new, kb, use_pallas()
         )
         t0 = time.perf_counter()
-        levels = get_guard().run(
-            self._label("restructure"),
-            lambda: fn(
-                self._levels[0], self._put(gather_padded, one_d=True),
-                jnp.asarray(fresh_pos), fresh,
-            ),
+        levels = get_guard().call(
+            self._label("restructure"), fn, self._levels[0],
+            self._put(gather_padded, one_d=True), jnp.asarray(fresh_pos),
+            jnp.asarray(fresh),
         )
         self._record_rebuild(t0)
         return levels
 
     # ------------------------------------------- per-shard routed scatter
     def _update_in_place(self, items: list[tuple[bytes, bytes]]) -> None:
-        """Value-only batch: route each key to its owning shard on the
+        """Value-only batch: hash the fresh leaves (size-bucketed, like the
+        single-device path), route each digest to its owning shard on the
         host, then ONE SPMD dispatch scatters every shard's sub-batch in
-        parallel (hash + leaf scatter + parent-path re-reduce + top tree).
-        Same batch shapes as the single-device path — global positions and
-        packed leaf blocks — just grouped by ``pos // L``."""
-        from merklekv_tpu.merkle.packing import pack_leaves
-
+        parallel (leaf scatter + parent-path re-reduce + top tree)."""
         k = len(items)
         d = self._n_shards
         l = self._capacity // d
         pos = self._positions([key for key, _ in items])
-        packed = pack_leaves(
-            [key for key, _ in items], [v for _, v in items]
+        fresh = leaf_digests(
+            [key for key, _ in items], [v for _, v in items],
+            label=self._label("scatter"),
         )
-        nblk = packed.max_blocks
         shard = pos // l
         local = pos % l
         counts = np.bincount(shard, minlength=d)
         kb = _bucket(int(counts.max()))
         # Routed tensors: dim 0 is the shard. Pad rows keep the scratch
-        # sentinel L as their index (the program drops them) and hash one
-        # zero block so every row is well-formed.
+        # sentinel L as their index (the program drops them).
         idx = np.full((d, kb), l, np.int32)
-        blocks = np.zeros((d, kb, nblk, 16), np.uint32)
-        nblocks = np.ones((d, kb), np.int32)
+        digests = np.zeros((d, kb, 8), np.uint32)
         order = np.argsort(shard, kind="stable")
         srt = shard[order]
         offs = np.arange(k) - np.searchsorted(srt, srt)
         idx[srt, offs] = local[order]
-        blocks[srt, offs] = packed.blocks[order]
-        nblocks[srt, offs] = packed.nblocks[order]
+        digests[srt, offs] = fresh[order]
 
         n_local = _local_level_count(self._capacity, d)
         t0 = time.perf_counter()
         fn = sharded_scatter_program(
-            self._mesh, self._axis, self._capacity, kb, nblk, use_pallas()
+            self._mesh, self._axis, self._capacity, kb, use_pallas()
         )
-        self._levels = get_guard().run(
-            self._label("scatter"),
-            lambda: fn(
-                *self._levels[:n_local],
-                self._put_routed(idx),
-                self._put_routed(blocks),
-                self._put_routed(nblocks),
-            ),
+        self._levels = get_guard().call(
+            self._label("scatter"), fn, *self._levels[:n_local],
+            self._put_routed(idx), self._put_routed(digests),
         )
         self.incremental_batches += 1
         m = get_metrics()
         m.inc("device.scatter_keys", k)
-        m.inc("device.scatter_bytes",
-              int(blocks.nbytes + idx.nbytes + nblocks.nbytes))
+        m.inc("device.scatter_bytes", int(digests.nbytes + idx.nbytes))
         m.observe("device.scatter_dispatch", time.perf_counter() - t0)

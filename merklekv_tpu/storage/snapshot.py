@@ -126,7 +126,7 @@ def compute_root_hex(
 
 
 def _device_root_hex(items: list[tuple[bytes, bytes]]) -> str:
-    jaxenv.ensure_platform()
+    jaxenv.configure_compile_cache()
     import numpy as np
 
     from merklekv_tpu.merkle.jax_engine import leaf_digests, tree_root

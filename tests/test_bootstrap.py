@@ -586,7 +586,7 @@ def test_soak_kill9_rejoin_processes(tmp_path):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
     def spawn(cfg_path):
-        env = dict(os.environ, PYTHONPATH=repo, MERKLEKV_JAX_PLATFORM="cpu")
+        env = dict(os.environ, PYTHONPATH=repo, JAX_PLATFORMS="cpu")
         return subprocess.Popen(
             [sys.executable, "-m", "merklekv_tpu", "--config", cfg_path],
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,

@@ -76,7 +76,7 @@ dispatch_deadline_ms = 120000
             env=dict(
                 os.environ,
                 PYTHONPATH=REPO,
-                MERKLEKV_JAX_PLATFORM="cpu",
+                JAX_PLATFORMS="cpu",
                 # The chaos hook: every sharded dispatch in the spawned
                 # process fails persistently (environment-shaped).
                 MKV_DEVICE_FAULTS="fail:shard*",

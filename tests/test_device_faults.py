@@ -133,6 +133,32 @@ def test_guard_nested_call_runs_inline_no_false_hang():
     assert g.run("outer", lambda: g.run("inner", lambda: 5)) == 5
 
 
+def test_guard_first_compile_is_not_a_hang():
+    """A program whose cold compile outlasts the deadline: ``call``
+    compiles it ahead of the guarded dispatch and succeeds, and the
+    executable is reused per signature; ``run`` around the same cold
+    compile is abandoned as a hang."""
+    import jax
+    import numpy as np
+
+    from merklekv_tpu.ops.sha256 import sha256_blocks
+
+    # CPU: ~0.4 s to compile, ~1 ms to run.
+    g = DispatchGuard(deadline_ms=150, policy=FAST)
+    blocks = np.zeros((4, 3, 16), np.uint32)
+    nb = np.ones(4, np.int32)
+    with pytest.raises(DispatchHungError):
+        g.run("build", lambda: jax.jit(sha256_blocks)(blocks, nb))
+    prog = jax.jit(sha256_blocks)
+    out = np.asarray(g.call("build", prog, blocks, nb))
+    np.testing.assert_array_equal(out, np.asarray(sha256_blocks(blocks, nb)))
+    assert g.compiled("build", prog, blocks, nb) is g.compiled(
+        "build", prog, blocks, nb
+    )
+    with pytest.raises(DeviceDispatchError):
+        g.compiled("build", prog, blocks, nb[:3])  # shapes do not trace
+
+
 # ------------------------------------------------------------ injector
 
 def test_injector_fail_nth_and_count():

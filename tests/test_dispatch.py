@@ -44,7 +44,7 @@ def test_production_paths_route_through_dispatch(monkeypatch):
     scatter, and restructure programs must reach the Pallas entry points.
     The spy delegates to the scan math so the roots stay correct on CPU."""
     import merklekv_tpu.ops.sha256_pallas as sp
-    from merklekv_tpu.merkle import incremental
+    from merklekv_tpu.merkle import incremental, jax_engine
     from merklekv_tpu.ops.sha256 import sha256_blocks, sha256_node_pairs
 
     calls = {"leaf": 0, "node": 0}
@@ -74,8 +74,9 @@ def test_production_paths_route_through_dispatch(monkeypatch):
     # Fresh compiled-program cache entries for the forced backend: the
     # factories key on use_pallas(), so these traces re-read the dispatch.
     incremental._build_fn.cache_clear()
-    incremental._scatter_hash_fn.cache_clear()
+    incremental._scatter_fn.cache_clear()
     incremental._restructure_fn.cache_clear()
+    jax_engine._leaf_fn.cache_clear()
 
     items = {b"rk%03d" % i: b"rv%d" % i for i in range(21)}
     st = incremental.DeviceMerkleState.from_items(items.items())
@@ -99,8 +100,9 @@ def test_production_paths_route_through_dispatch(monkeypatch):
 
     # Cleanup: drop the spy-traced programs so later tests re-trace real ones.
     incremental._build_fn.cache_clear()
-    incremental._scatter_hash_fn.cache_clear()
+    incremental._scatter_fn.cache_clear()
     incremental._restructure_fn.cache_clear()
+    jax_engine._leaf_fn.cache_clear()
 
 
 def test_sharded_step_routes_through_dispatch(monkeypatch):

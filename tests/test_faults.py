@@ -167,9 +167,9 @@ def test_peer_death_mid_sync_checkpoints_and_resumes(make_pair):
     p = make_pair(seed=23, divergent=120, mget_batch=8)
     repairs: list[bytes] = []
 
-    def killer_listener(key, value, ts=None):
-        repairs.append(key)
-        if len(repairs) == 20:
+    def killer_listener(batch):
+        repairs.extend(key for key, _, _ in batch)
+        if len(repairs) - len(batch) < 20 <= len(repairs):
             p.inj.kill_peer()
 
     p.mgr._repair_listener = killer_listener
@@ -231,11 +231,12 @@ def test_multi_peer_cycle_survives_mid_sync_peer_death(make_pair):
     degraded: list[str] = []
     killed = []
 
-    def listener(key, value, ts=None):
-        # First b-key repair kills peer B mid-stream.
-        if key.startswith(b"b") and not killed:
-            killed.append(key)
-            inj_b.kill_peer()
+    def listener(batch):
+        # First batch of b-key repairs kills peer B mid-stream.
+        for key, _, _ in batch:
+            if key.startswith(b"b") and not killed:
+                killed.append(key)
+                inj_b.kill_peer()
 
     mgr = SyncManager(
         local,

@@ -614,7 +614,7 @@ def test_blackbox_merges_ordered_timeline_with_trace_links(tmp_path):
 
 def test_blackbox_flags_device_shortfall_as_environment(tmp_path):
     """A multichip probe spill whose device-enumerate phase shows fewer
-    devices than requested (MULTICHIP_r01's failure mode) surfaces as an
+    devices than requested (a device-complement shortfall) surfaces as an
     ENVIRONMENT anomaly — triage reads driver weather, not a regression —
     while a full-complement enumerate stays silent."""
     d = tmp_path / "probe"

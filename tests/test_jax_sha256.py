@@ -104,3 +104,42 @@ def test_pack_leaves_matches_encode_leaf():
 def test_pack_leaves_empty():
     packed = pack_leaves([], [])
     assert packed.n == 0
+
+
+def test_pack_leaf_groups_bucket_by_pow2_width(monkeypatch):
+    """Whole-keyspace packing groups leaves by power-of-two block count:
+    one large value never pads the small rows, every leaf lands in exactly
+    one group, and each group stays within GROUP_BLOCKS."""
+    from merklekv_tpu.merkle import packing
+
+    monkeypatch.setattr(packing, "GROUP_BLOCKS", 64)
+    keys = [b"user%d" % i for i in range(40)] + [b"big4k", b"big64k"]
+    values = [b"x" * 1010] * 40 + [b"y" * 4096, b"z" * 65536]
+    seen = []
+    for rows, packed in packing.pack_leaf_groups(keys, values):
+        width = packed.max_blocks
+        assert width & (width - 1) == 0
+        n = packed.n
+        assert n & (n - 1) == 0 and n >= len(rows)
+        assert n * width <= 64 or len(rows) == 1
+        assert list(rows) == sorted(rows)
+        got = digests_to_bytes(sha256_blocks(packed.blocks, packed.nblocks))
+        for r, g in zip(rows, got):
+            assert g == leaf_hash(keys[r], values[r])
+        seen += list(rows)
+    assert sorted(seen) == list(range(len(keys)))
+    widths = {
+        packed.max_blocks for _, packed in packing.pack_leaf_groups(
+            keys[:40], values[:40])
+    }
+    assert widths == {32}  # ~1 KB records: 17 blocks -> the 32 bucket
+
+
+def test_leaf_digests_mixed_sizes_in_input_order():
+    from merklekv_tpu.merkle.jax_engine import leaf_digests
+
+    keys = [b"k%03d" % i for i in range(50)]
+    values = [b"v" * (i * 97 % 3000) for i in range(50)]
+    got = digests_to_bytes(leaf_digests(keys, values))
+    assert got == [leaf_hash(k, v) for k, v in zip(keys, values)]
+    assert leaf_digests([], []).shape == (0, 8)

@@ -24,7 +24,6 @@ DIVERGED = 5   # seeded divergent keys on replica 1
 _WORKER = r"""
 import os, sys
 import jax
-jax.config.update("jax_platforms", "cpu")
 sys.path.insert(0, {repo!r})
 
 from merklekv_tpu.parallel import multihost, sharded_anti_entropy_step
@@ -96,12 +95,13 @@ def test_two_process_cluster_agrees_with_golden(tmp_path):
         k: v
         for k, v in os.environ.items()
         # Workers pick their own device count; drop the suite's 8-device
-        # flag and the pinned platform.
-        if k not in ("XLA_FLAGS", "JAX_PLATFORMS")
+        # flag.
+        if k != "XLA_FLAGS"
     }
     for pid in range(2):
         env = dict(
             env_base,
+            JAX_PLATFORMS="cpu",
             XLA_FLAGS="--xla_force_host_platform_device_count=4",
             MKV_COORDINATOR=f"127.0.0.1:{port}",
             MKV_NUM_PROCESSES="2",

@@ -27,7 +27,7 @@ P, R = 2, 2
 
 
 def _spawn(args):
-    env = dict(os.environ, PYTHONPATH=REPO, MERKLEKV_JAX_PLATFORM="cpu")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     return subprocess.Popen(
         [sys.executable, *args],
         stdout=subprocess.PIPE,

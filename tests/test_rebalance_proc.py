@@ -81,7 +81,7 @@ class ProcCluster:
 
     def _spawn(self, args):
         env = dict(os.environ, PYTHONPATH=REPO,
-                   MERKLEKV_JAX_PLATFORM="cpu", **CHAOS_ENV)
+                   JAX_PLATFORMS="cpu", **CHAOS_ENV)
         return subprocess.Popen(
             [sys.executable, *args],
             stdout=subprocess.PIPE,
@@ -315,7 +315,7 @@ def test_kill9_donor_mid_transfer_joiner_aborts_blackbox_parses(cluster):
         rc = subprocess.run(
             [sys.executable, "-m", "merklekv_tpu", "blackbox", flight],
             env=dict(os.environ, PYTHONPATH=REPO,
-                     MERKLEKV_JAX_PLATFORM="cpu"),
+                     JAX_PLATFORMS="cpu"),
             stdout=subprocess.DEVNULL,
             stderr=subprocess.STDOUT,
         ).returncode

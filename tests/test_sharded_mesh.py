@@ -26,7 +26,6 @@ pytestmark = pytest.mark.integration
 _SWEEP = r"""
 import json
 import jax
-jax.config.update("jax_platforms", "cpu")
 import sys
 sys.path.insert(0, @@REPO@@)
 
@@ -94,8 +93,9 @@ def test_eight_way_host_mesh_parity(tmp_path):
     env = {
         k: v
         for k, v in os.environ.items()
-        if k not in ("XLA_FLAGS", "JAX_PLATFORMS")
+        if k != "XLA_FLAGS"
     }
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     out = subprocess.run(
         [sys.executable, str(script)],

@@ -31,7 +31,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _spawn(args):
-    env = dict(os.environ, PYTHONPATH=REPO, MERKLEKV_JAX_PLATFORM="cpu")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     return subprocess.Popen(
         [sys.executable, *args],
         stdout=subprocess.PIPE,

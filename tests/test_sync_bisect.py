@@ -110,6 +110,62 @@ def test_hashpage_upto_bounds_the_page(two_nodes):
             c.leaf_hashes_page(10, "", upto="hp04")
 
 
+def test_hashpage_follows_the_key_index_through_churn(monkeypatch):
+    """HASHPAGE pages come from the engine's sorted key index: after sets,
+    tombstoned and quiet deletes, re-sets, tombstone eviction and a
+    truncate, paging the whole keyspace (and one bounded range) yields
+    exactly the live keys and tombstones, in order, each once."""
+    import random
+
+    monkeypatch.setenv("MKV_MAX_TOMBS_PER_SHARD", "4")  # force evictions
+    eng = NativeEngine("mem")
+    srv = NativeServer(eng, "127.0.0.1", 0)
+    srv.start()
+    rng = random.Random(7)
+
+    def expected(lo: str = "", hi: str = "\U0010ffff") -> list:
+        live = {k.decode(): False for k in eng.scan()}
+        tombs = {k.decode(): True for k, _ in eng.tombstones()}
+        assert not live.keys() & tombs.keys()
+        rows = sorted({**live, **tombs}.items())
+        return [r for r in rows if lo < r[0] < hi]
+
+    def paged(c, size: int) -> list:
+        out, cursor = [], ""
+        while True:
+            rows, done = c.leaf_hashes_page(size, cursor)
+            out += [(k, h is None) for k, h, _ in rows]
+            if done:
+                return out
+            cursor = rows[-1][0]
+
+    try:
+        with MerkleKVClient("127.0.0.1", srv.port) as c:
+            for step in range(600):
+                k = f"ix{rng.randrange(200):03d}".encode()
+                op = rng.random()
+                if op < 0.55:
+                    eng.set(k, b"v%d" % step)
+                elif op < 0.85:
+                    eng.delete(k)
+                else:
+                    eng.delete_quiet(k)
+            assert eng.tomb_evictions() > 0
+            want = expected()
+            assert len(want) > 100
+            for size in (1, 7, 512):
+                assert paged(c, size) == want
+            rows, done = c.leaf_hashes_page(1000, "ix050", upto="ix120")
+            assert done
+            assert [(k, h is None) for k, h, _ in rows] == expected(
+                "ix050", "ix120")
+            eng.truncate()
+            assert paged(c, 16) == []
+    finally:
+        srv.close()
+        eng.close()
+
+
 # ------------------------------------------------------------ the walk
 
 
