@@ -61,6 +61,7 @@ from merklekv_tpu.device.ladder import (
 from merklekv_tpu.native_bindings import NativeEngine
 from merklekv_tpu.obs.metrics import get_metrics
 from merklekv_tpu.utils.errorkind import classify_exception
+from merklekv_tpu.utils.tracing import span
 
 __all__ = ["DeviceTreeMirror"]
 
@@ -350,7 +351,7 @@ class DeviceTreeMirror:
         ``watermark`` is the engine version read BEFORE the queue drain
         (conservative coverage — see the module docstring); None falls back
         to a read at staging time."""
-        with self._mu:
+        with span("mirror.stage_local", log=False), self._mu:
             if self._closed:
                 return
             if self._state is None:
@@ -400,7 +401,7 @@ class DeviceTreeMirror:
         rates the lock/stage overhead, not the device math, dominated)."""
         if not pairs:
             return
-        with self._mu:
+        with span("mirror.stage_remote", log=False), self._mu:
             if self._closed:
                 return
             if self._state is None:
@@ -488,9 +489,11 @@ class DeviceTreeMirror:
                     and ver_lag >= self._max_lag_versions
                 )
                 if wait > 0 and not deep:
-                    time.sleep(min(wait, self._window_s / 2))
+                    with span("pump.coalesce_wait", log=False):
+                        time.sleep(min(wait, self._window_s / 2))
                 try:
-                    self.publish_now()
+                    with span("pump.drain", log=False):
+                        self.publish_now()
                     get_metrics().inc("device.pump_batches")
                     if self._ladder is not None:
                         self._ladder.note_success()
@@ -560,7 +563,8 @@ class DeviceTreeMirror:
                 # invalidate (native fallback answers, re-warm restores)
                 # and let the raised error feed the ladder as usual.
                 try:
-                    root = self._state.root_hex(flush=False)
+                    with span("device.root_walk", log=False):
+                        root = self._state.root_hex(flush=False)
                 except BaseException:
                     self.invalidate()
                     raise
@@ -577,8 +581,15 @@ class DeviceTreeMirror:
         self._published_gen += 1
         # Root recomputed lazily, cached per generation in _pub_snapshot.
         self._pub_snapshot = (None, self._published_version)
+        now = time.monotonic()
+        if self._staged_since_m is not None:
+            # The in-program counterpart of a reader's tree lag: how long
+            # the oldest change this publish covers waited to be served.
+            get_metrics().observe_span(
+                "pump.staged_age", now - self._staged_since_m
+            )
         self._staged_since_m = None
-        self._last_publish_m = time.monotonic()
+        self._last_publish_m = now
 
     def _check_staleness_breach(self) -> None:
         """Flight-recorder hook: one ``tree_staleness`` event per flag

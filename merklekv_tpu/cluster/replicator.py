@@ -48,7 +48,7 @@ from merklekv_tpu.cluster.change_event import (
 from merklekv_tpu.obs import tracewire
 from merklekv_tpu.cluster.retry import REPLICATOR_PUBLISH, RetryPolicy
 from merklekv_tpu.cluster.transport import Transport
-from merklekv_tpu.utils.tracing import get_metrics
+from merklekv_tpu.utils.tracing import get_metrics, span
 from merklekv_tpu.native_bindings import (
     OP_APPEND,
     OP_DECR,
@@ -237,40 +237,44 @@ class Replicator:
             raws = self._server.drain_events()
             if not raws:
                 return 0
-            events = [self._to_event(r) for r in raws]
-            # Mirror first: once events leave the native queue they are the
-            # mirror's only chance to see these keys — a publish failure
-            # must not cost the mirror the batch. Staging is host-side and
-            # cheap; the mirror's pump owns the device dispatch, so this
-            # drain thread (and the write path behind it) never waits on
-            # the device plane.
-            if self._mirror is not None:
-                try:
-                    self._mirror.on_events(events, watermark=watermark)
-                except Exception:
-                    # Device trouble: a silently-dropped batch would serve a
-                    # divergent root forever; invalidate so HASH falls back
-                    # to the native path until a re-warm succeeds.
-                    self._mirror.invalidate()
-            # TRUNCATE stays local: it only invalidates device mirrors.
-            publishable = [ev for ev in events if ev.op is not OpKind.TRUNCATE]
-            if self._batch_max_events <= 1:
-                published = self._publish_per_event(publishable)
-            else:
-                published = self._publish_frames(publishable)
-            self.published += published
-            if published:
-                # Registry mirror of the instance counters so METRICS (and
-                # the /metrics endpoint) can see replication flow without a
-                # handle on this object.
-                get_metrics().inc("replicator.published", published)
-            self._range_forward(publishable)
-            if self._batch_listener is not None:
-                try:
-                    self._batch_listener(events)
-                except Exception:
-                    pass
-            return len(events)
+            with span("replication.flush", log=False):
+                events = [self._to_event(r) for r in raws]
+                # Mirror first: once events leave the native queue they are
+                # the mirror's only chance to see these keys — a publish
+                # failure must not cost the mirror the batch. Staging is
+                # host-side; the mirror's pump owns the device dispatch, so
+                # this drain thread dispatches nothing itself, though it
+                # waits for the mirror's lock while a pump drain holds it.
+                if self._mirror is not None:
+                    try:
+                        self._mirror.on_events(events, watermark=watermark)
+                    except Exception:
+                        # Device trouble: a silently-dropped batch would
+                        # serve a divergent root forever; invalidate so HASH
+                        # falls back to the native path until a re-warm
+                        # succeeds.
+                        self._mirror.invalidate()
+                # TRUNCATE stays local: it only invalidates device mirrors.
+                publishable = [
+                    ev for ev in events if ev.op is not OpKind.TRUNCATE
+                ]
+                if self._batch_max_events <= 1:
+                    published = self._publish_per_event(publishable)
+                else:
+                    published = self._publish_frames(publishable)
+                self.published += published
+                if published:
+                    # Registry mirror of the instance counters so METRICS
+                    # (and the /metrics endpoint) can see replication flow
+                    # without a handle on this object.
+                    get_metrics().inc("replicator.published", published)
+                self._range_forward(publishable)
+                if self._batch_listener is not None:
+                    try:
+                        self._batch_listener(events)
+                    except Exception:
+                        pass
+                return len(events)
 
     # -- rebalance range-forward --------------------------------------------
     def set_range_forward(
@@ -462,68 +466,70 @@ class Replicator:
 
     # -- inbound ------------------------------------------------------------
     def _on_message(self, topic: str, payload: bytes) -> None:
-        try:
-            events, meta = decode_events_meta(payload)
-        except ValueError:
-            # Malformed messages (and unknown envelope versions) are
-            # tolerated, like the reference's decoder fallthrough
-            # (replication.rs:150-157) — counted, never applied partially.
-            self.decode_errors += 1
-            get_metrics().inc("replicator.decode_errors")
-            return
-        events = [ev for ev in events if ev.src != self.node_id]  # no echo
-        if not events:
-            return
-        events = self._clamp_skew(events)
-        self.received += len(events)
-        get_metrics().inc("replicator.received", len(events))
-        # Remote-apply side of the rebalance double-apply: a moving-range
-        # write that landed on a SIBLING replica arrives here on the group
-        # topic — relay it to the joiner too (the sibling doesn't forward;
-        # only the donor node arms this). Runs before the hold check so a
-        # frame buffered by a concurrent bootstrap still reaches the
-        # joiner.
-        self._range_forward(events)
-        if self._lag is not None:
-            # Record the publish HWM at DECODE time: a frame held by a
-            # bootstrap (or stuck behind a slow apply) reads as lag until
-            # its apply accounts for it.
-            self._lag.on_frame(
-                meta.get("src", ""),
-                len(events),
-                hseq=meta.get("hseq", 0),
-                hts_ns=meta.get("hts", 0),
-            )
-        with self._applier_mu:
-            if self._holding:
-                # Journal NOW (recovery replay is LWW-conditional, so
-                # journaling an event the replay later rejects is safe),
-                # apply after the verified snapshot lands.
-                if self._storage is not None:
-                    self._storage.record_applied(
-                        [
-                            (
-                                ev.key.encode("utf-8", "surrogateescape"),
-                                None if ev.op is OpKind.DEL else ev.val,
-                                ev.ts,
-                            )
-                            for ev in events
-                            if ev.op is not OpKind.TRUNCATE
-                        ]
-                    )
-                if len(self._held) < self._MAX_HELD_FRAMES:
-                    self._held.append((events, meta))
-                    self.buffered += len(events)
-                    get_metrics().inc("replicator.buffered", len(events))
-                else:
-                    # Journaled but not replayable in RAM: anti-entropy
-                    # repairs the residue (frame-loss semantics, counted;
-                    # the lag plane keeps showing it until a converged
-                    # anti-entropy cycle clears the residue).
-                    get_metrics().inc("replicator.buffer_dropped",
-                                      len(events))
+        with span("replication.apply_frame", log=False):
+            try:
+                events, meta = decode_events_meta(payload)
+            except ValueError:
+                # Malformed messages (and unknown envelope versions) are
+                # tolerated, like the reference's decoder fallthrough
+                # (replication.rs:150-157) — counted, never applied
+                # partially.
+                self.decode_errors += 1
+                get_metrics().inc("replicator.decode_errors")
                 return
-            self._apply_frame(events, journal=True, meta=meta)
+            events = [ev for ev in events if ev.src != self.node_id]  # no echo
+            if not events:
+                return
+            events = self._clamp_skew(events)
+            self.received += len(events)
+            get_metrics().inc("replicator.received", len(events))
+            # Remote-apply side of the rebalance double-apply: a
+            # moving-range write that landed on a SIBLING replica arrives
+            # here on the group topic — relay it to the joiner too (the
+            # sibling doesn't forward; only the donor node arms this). Runs
+            # before the hold check so a frame buffered by a concurrent
+            # bootstrap still reaches the joiner.
+            self._range_forward(events)
+            if self._lag is not None:
+                # Record the publish HWM at DECODE time: a frame held by a
+                # bootstrap (or stuck behind a slow apply) reads as lag until
+                # its apply accounts for it.
+                self._lag.on_frame(
+                    meta.get("src", ""),
+                    len(events),
+                    hseq=meta.get("hseq", 0),
+                    hts_ns=meta.get("hts", 0),
+                )
+            with self._applier_mu:
+                if self._holding:
+                    # Journal NOW (recovery replay is LWW-conditional, so
+                    # journaling an event the replay later rejects is safe),
+                    # apply after the verified snapshot lands.
+                    if self._storage is not None:
+                        self._storage.record_applied(
+                            [
+                                (
+                                    ev.key.encode("utf-8", "surrogateescape"),
+                                    None if ev.op is OpKind.DEL else ev.val,
+                                    ev.ts,
+                                )
+                                for ev in events
+                                if ev.op is not OpKind.TRUNCATE
+                            ]
+                        )
+                    if len(self._held) < self._MAX_HELD_FRAMES:
+                        self._held.append((events, meta))
+                        self.buffered += len(events)
+                        get_metrics().inc("replicator.buffered", len(events))
+                    else:
+                        # Journaled but not replayable in RAM: anti-entropy
+                        # repairs the residue (frame-loss semantics, counted;
+                        # the lag plane keeps showing it until a converged
+                        # anti-entropy cycle clears the residue).
+                        get_metrics().inc("replicator.buffer_dropped",
+                                          len(events))
+                    return
+                self._apply_frame(events, journal=True, meta=meta)
 
     def _clamp_skew(self, events: list[ChangeEvent]) -> list[ChangeEvent]:
         """Clamp future-poisoned timestamps to now + max_skew_ms, counted

@@ -40,6 +40,7 @@ from typing import Callable, Optional, TypeVar
 from merklekv_tpu.cluster.retry import DEVICE_DISPATCH, RetryPolicy
 from merklekv_tpu.obs.metrics import get_metrics
 from merklekv_tpu.utils.errorkind import CODE, ENVIRONMENT, classify_exception
+from merklekv_tpu.utils.tracing import span
 
 __all__ = [
     "DeviceDispatchError",
@@ -52,6 +53,10 @@ __all__ = [
 ]
 
 T = TypeVar("T")
+
+# Guarded calls running on this thread: a call made inside another one
+# (inline or on its guard thread) is part of it, not a dispatch of its own.
+_active = threading.local()
 
 
 class DeviceDispatchError(RuntimeError):
@@ -174,7 +179,8 @@ class DispatchGuard:
         exe = self._executables.get(key)
         if exe is None:
             try:
-                exe = program.lower(*args).compile()
+                with span("device.compile", label=label):
+                    exe = program.lower(*args).compile()
             except Exception as e:
                 get_metrics().inc("device.guard_errors")
                 raise DeviceDispatchError(
@@ -199,12 +205,23 @@ class DispatchGuard:
     def run(self, label: str, fn: Callable[[], T]) -> T:
         """Run one device program call under the guard. Returns ``fn()``'s
         value, or raises :class:`DeviceDispatchError` (classified) /
-        :class:`DispatchHungError` (abandoned)."""
+        :class:`DispatchHungError` (abandoned). Each call that is not
+        nested in another counts once in ``device.dispatches``."""
+        if not getattr(_active, "depth", 0):
+            get_metrics().inc("device.dispatches")
+
+        def guarded() -> T:
+            _active.depth = getattr(_active, "depth", 0) + 1
+            try:
+                return fn()
+            finally:
+                _active.depth -= 1
+
         inject = get_inject()
         if inject is not None:
-            call = lambda: inject.around(label, fn)  # noqa: E731
+            call = lambda: inject.around(label, guarded)  # noqa: E731
         else:
-            call = fn
+            call = guarded
         retried = False
         while True:
             try:

@@ -32,7 +32,7 @@ so a 10M-key tree costs the host one object array, not a value map.
 
 from __future__ import annotations
 
-from functools import lru_cache
+from functools import lru_cache, partial
 from typing import Iterable, Optional, Sequence
 
 import numpy as np
@@ -53,6 +53,7 @@ from merklekv_tpu.ops.sha256 import (
     digests_to_bytes,
     sha256_node_pairs,
 )
+from merklekv_tpu.utils.tracing import span
 
 __all__ = ["DeviceMerkleState"]
 
@@ -84,6 +85,8 @@ def _reduce_levels(leaves: jax.Array) -> tuple:
 # between traces (tests forcing MKV_SHA256_BACKEND) can't replay a program
 # compiled for the other formulation. Every call site runs its program
 # through the dispatch guard's ahead-of-time compile (device/guard.py).
+# Each program is named ``mkv_<what>`` and its body sits in a named scope
+# of that name, so a profiler trace attributes its device ops by name.
 
 @lru_cache(maxsize=None)
 def _build_fn(capacity: int, pallas: bool):
@@ -93,10 +96,11 @@ def _build_fn(capacity: int, pallas: bool):
     del pallas  # cache key only; _reduce_levels re-reads the dispatch
 
     @jax.jit
-    def go(leaves: jax.Array):
-        return _reduce_levels(leaves)
+    def mkv_build_levels(leaves: jax.Array):
+        with jax.named_scope("mkv_build_levels"):
+            return _reduce_levels(leaves)
 
-    return go
+    return mkv_build_levels
 
 
 def _scatter_levels(levels: tuple, idx: jax.Array, new_leaves: jax.Array):
@@ -125,10 +129,11 @@ def _scatter_fn(capacity: int, kb: int, pallas: bool):
     del pallas
 
     @jax.jit
-    def go(levels: tuple, idx: jax.Array, new_leaves: jax.Array):
-        return _scatter_levels(levels, idx, new_leaves)
+    def mkv_scatter(levels: tuple, idx: jax.Array, new_leaves: jax.Array):
+        with jax.named_scope("mkv_scatter"):
+            return _scatter_levels(levels, idx, new_leaves)
 
-    return go
+    return mkv_scatter
 
 
 @lru_cache(maxsize=None)
@@ -143,14 +148,15 @@ def _restructure_fn(c_old: int, c_new: int, kb: int, pallas: bool):
     del pallas
 
     @jax.jit
-    def go(old_leaves, gather_idx, fresh_pos, fresh):
-        safe = jnp.clip(gather_idx, 0, max(c_old - 1, 0))
-        base = jnp.where((gather_idx >= 0)[:, None], old_leaves[safe], 0)
-        if kb:
-            base = base.at[fresh_pos].set(fresh)
-        return _reduce_levels(base)
+    def mkv_restructure(old_leaves, gather_idx, fresh_pos, fresh):
+        with jax.named_scope("mkv_restructure"):
+            safe = jnp.clip(gather_idx, 0, max(c_old - 1, 0))
+            base = jnp.where((gather_idx >= 0)[:, None], old_leaves[safe], 0)
+            if kb:
+                base = base.at[fresh_pos].set(fresh)
+            return _reduce_levels(base)
 
-    return go
+    return mkv_restructure
 
 
 @lru_cache(maxsize=None)
@@ -159,27 +165,38 @@ def _ref_root_fn(capacity: int):
     reference odd-promotion root over the first n leaves."""
 
     @jax.jit
-    def go(levels: tuple, n: jax.Array):
-        m = jnp.asarray(n, jnp.int32)
-        last = jax.lax.dynamic_index_in_dim(
-            levels[0], jnp.maximum(m - 1, 0), axis=0, keepdims=False
-        )
-        for lvl in range(1, len(levels)):
-            odd = (m % 2) == 1
-            # Even m: reference's next last = H(level[m-2], last). Position
-            # m-2 of the reference level equals the padded level (only the
-            # last position can differ).
-            prev = jax.lax.dynamic_index_in_dim(
-                levels[lvl - 1], jnp.maximum(m - 2, 0), axis=0, keepdims=False
+    def mkv_root_walk(levels: tuple, n: jax.Array):
+        with jax.named_scope("mkv_root_walk"):
+            m = jnp.asarray(n, jnp.int32)
+            last = jax.lax.dynamic_index_in_dim(
+                levels[0], jnp.maximum(m - 1, 0), axis=0, keepdims=False
             )
-            combined = sha256_node_pairs(prev[None], last[None])[0]
-            # Odd m: the tail is promoted unchanged. m == 1: stay at root.
-            new_last = jnp.where(odd, last, combined)
-            last = jnp.where(m <= 1, last, new_last)
-            m = jnp.where(m <= 1, m, (m + 1) // 2)
-        return last
+            for lvl in range(1, len(levels)):
+                odd = (m % 2) == 1
+                # Even m: reference's next last = H(level[m-2], last).
+                # Position m-2 of the reference level equals the padded
+                # level (only the last position can differ).
+                prev = jax.lax.dynamic_index_in_dim(
+                    levels[lvl - 1], jnp.maximum(m - 2, 0), axis=0,
+                    keepdims=False,
+                )
+                combined = sha256_node_pairs(prev[None], last[None])[0]
+                # Odd m: the tail is promoted unchanged. m == 1: stay at
+                # root.
+                new_last = jnp.where(odd, last, combined)
+                last = jnp.where(m <= 1, last, new_last)
+                m = jnp.where(m <= 1, m, (m + 1) // 2)
+            return last
 
-    return go
+    return mkv_root_walk
+
+
+@partial(jax.jit, static_argnames="width")
+def mkv_level_gather(level: jax.Array, start: jax.Array, width: int):
+    """``width`` rows of one padded level from a traced ``start``: one
+    compiled gather per (level, width) however many slices are asked."""
+    with jax.named_scope("mkv_level_gather"):
+        return jax.lax.dynamic_slice_in_dim(level, start, width)
 
 
 class DeviceMerkleState:
@@ -345,7 +362,8 @@ class DeviceMerkleState:
             if updates and self._levels is not None:
                 self._update_in_place(updates)
             return
-        self._restructure(deletes, upserts, inserts)
+        with span("device.restructure", log=False):
+            self._restructure(deletes, upserts, inserts)
 
     def _update_in_place(self, items: list[tuple[bytes, bytes]]) -> None:
         k = len(items)
@@ -356,33 +374,24 @@ class DeviceMerkleState:
         # Pad rows repeat row 0's digest, so duplicate scatters write
         # identical values.
         digests = np.empty((kb, 8), np.uint32)
-        digests[:k] = leaf_digests(
-            [key for key, _ in items], [v for _, v in items],
-            label=self._label("scatter"),
-        )
+        with span("device.leaf_hash", log=False):
+            digests[:k] = leaf_digests(
+                [key for key, _ in items], [v for _, v in items],
+                label=self._label("scatter"),
+            )
         digests[k:] = digests[0]
-        # Device-plane attribution (batch size + host->device transfer
-        # bytes): counters + a DISPATCH-latency histogram, no per-batch log
-        # line — a sustained drain flushes many times per second and span()
-        # would turn the log into the hot path. JAX dispatch is async, so
-        # the histogram measures trace+enqueue cost (queue-pressure
-        # signal), NOT on-device execution — forcing completion per batch
-        # (a host fetch) would serialize the very pipelining the drain
-        # depends on; end-to-end device time shows up in the spans that
-        # already force a root read (mirror warm, storage snapshot stamp).
-        import time as _time
-
-        t0 = _time.perf_counter()
-        fn = _scatter_fn(self._capacity, kb, use_pallas())
-        self._levels = get_guard().call(
-            self._label("scatter"), fn, self._levels, jnp.asarray(idx),
-            jnp.asarray(digests),
-        )
+        # JAX dispatch is asynchronous: this span times the enqueue; the
+        # device's work on it completes inside the next root walk's fetch.
+        with span("device.scatter", log=False):
+            fn = _scatter_fn(self._capacity, kb, use_pallas())
+            self._levels = get_guard().call(
+                self._label("scatter"), fn, self._levels, jnp.asarray(idx),
+                jnp.asarray(digests),
+            )
         self.incremental_batches += 1
         m = get_metrics()
         m.inc("device.scatter_keys", k)
         m.inc("device.scatter_bytes", int(digests.nbytes + idx.nbytes))
-        m.observe("device.scatter_dispatch", _time.perf_counter() - t0)
 
     # ------------------------------------------------------------ structure
     def _capacity_for(self, n: int) -> int:
@@ -399,8 +408,6 @@ class DeviceMerkleState:
         )
 
     def _initial_build(self, keys_arr: np.ndarray, values: list) -> None:
-        from merklekv_tpu.utils.tracing import span
-
         n = len(keys_arr)
         c = self._capacity_for(n)
         # Full rebuilds are rare (warm-up, empty->non-empty restructure) and
@@ -479,19 +486,17 @@ class DeviceMerkleState:
             # is a device dispatch and must not be able to wedge the
             # pump thread outside the deadline.
             fresh = np.empty((kb, 8), np.uint32)
-            fresh[:k] = leaf_digests(
-                [key for key, _ in fresh_items],
-                [v for _, v in fresh_items],
-                label=self._label("restructure"),
-            )
+            with span("device.leaf_hash", log=False):
+                fresh[:k] = leaf_digests(
+                    [key for key, _ in fresh_items],
+                    [v for _, v in fresh_items],
+                    label=self._label("restructure"),
+                )
             fresh[k:] = fresh[0]
         else:
             fresh_pos = np.zeros(0, np.int32)
             fresh = np.zeros((0, 8), np.uint32)
 
-        import time as _time
-
-        t0 = _time.perf_counter()
         self._levels = self._dispatch_restructure(
             gather_padded, fresh_pos, fresh, kb, c_new
         )
@@ -502,8 +507,6 @@ class DeviceMerkleState:
         m.inc("device.restructure_keys", k)
         m.inc("device.restructure_bytes",
               int(gather_padded.nbytes + fresh_pos.nbytes + k * 32))
-        # Dispatch latency, same async-enqueue semantics as scatter above.
-        m.observe("device.restructure_dispatch", _time.perf_counter() - t0)
 
     # ------------------------------------------------- device dispatch seam
     # The host planning above (classification, permutation index arithmetic,
@@ -634,7 +637,7 @@ class DeviceMerkleState:
             width = min(_next_pow2(hi - lo), arr.shape[0])
             start = min(lo, arr.shape[0] - width)
             window = np.asarray(
-                jax.lax.dynamic_slice_in_dim(arr, np.int32(start), width)
+                mkv_level_gather(arr, np.int32(start), width)
             )
             digs = digests_to_bytes(window[lo - start : hi - start])
             rows = [(lo + i, d) for i, d in enumerate(digs)]

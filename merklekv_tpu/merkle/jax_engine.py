@@ -84,17 +84,21 @@ def anti_entropy_forward_pallas(blocks, nblocks, digests, present):
 
 # ------------------------------------------------------------ leaf hashing
 
-@jax.jit
-def _leaf_digests_jit(blocks: jax.Array, nblocks: jax.Array) -> jax.Array:
-    return sha256_blocks(blocks, nblocks)
-
-
 @lru_cache(maxsize=None)
 def _leaf_fn(pallas: bool):
-    """The jitted leaf-hash program for the dispatch backend."""
+    """The jitted leaf-hash program for the dispatch backend: [N, B, 16]
+    padded blocks + [N] valid counts -> [N, 8] digests. A fresh function
+    per backend, so JAX's trace cache never replays the other backend's
+    program (``hash_blocks`` re-reads the dispatch at trace time)."""
+    del pallas  # cache key only
     from merklekv_tpu.ops.dispatch import hash_blocks
 
-    return jax.jit(hash_blocks) if pallas else _leaf_digests_jit
+    @jax.jit
+    def mkv_leaf_digests(blocks: jax.Array, nblocks: jax.Array):
+        with jax.named_scope("mkv_leaf_digests"):
+            return hash_blocks(blocks, nblocks)
+
+    return mkv_leaf_digests
 
 
 def leaf_digests(

@@ -146,10 +146,9 @@ CATALOG: dict[str, tuple[str, str]] = {
         "counter", "Keys in structural (insert/delete) device batches."),
     "device.restructure_bytes": (
         "counter", "Bytes transferred by structural device batches."),
-    "device.scatter_dispatch": (
-        "histogram", "Scatter-batch dispatch (async enqueue) latency."),
-    "device.restructure_dispatch": (
-        "histogram", "Structural-batch dispatch (async enqueue) latency."),
+    "device.dispatches": (
+        "counter", "Guarded device program calls, each counted once (a "
+        "call nested in another is part of it)."),
     "device.pump_batches": (
         "counter", "Device-update pump drain cycles published (staged "
         "events -> scatter dispatch -> served snapshot)."),

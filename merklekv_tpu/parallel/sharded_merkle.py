@@ -78,13 +78,15 @@ def _tree_root_program(mesh: Mesh, axis: str, pallas: bool):
         out_specs=P(None, None),
         check_vma=False,
     )
-    def go(block):
-        _check_local_block(block.shape[0])
-        local = _local_root(block)  # [1, 8]
-        roots = jax.lax.all_gather(local, axis, axis=0, tiled=True)  # [D, 8]
-        return build_levels(roots)[-1]  # [1, 8], same on every shard
+    def mkv_shard_tree_root(block):
+        with jax.named_scope("mkv_shard_tree_root"):
+            _check_local_block(block.shape[0])
+            local = _local_root(block)  # [1, 8]
+            # [D, 8] shard roots, then [1, 8], the same on every shard.
+            roots = jax.lax.all_gather(local, axis, axis=0, tiled=True)
+            return build_levels(roots)[-1]
 
-    return jax.jit(go)
+    return jax.jit(mkv_shard_tree_root)
 
 
 def sharded_tree_root(mesh: Mesh, leaves: jax.Array, axis: str = "key") -> jax.Array:
@@ -127,12 +129,15 @@ def divergence_program(mesh: Mesh, axis: str):
         out_specs=(P(None, axis), P(None)),
         check_vma=False,
     )
-    def go(dig, pres):
-        masks = divergence_masks(dig, pres)
-        counts = jax.lax.psum(jnp.sum(masks, axis=1, dtype=jnp.int32), axis)
-        return masks, counts
+    def mkv_shard_divergence(dig, pres):
+        with jax.named_scope("mkv_shard_divergence"):
+            masks = divergence_masks(dig, pres)
+            counts = jax.lax.psum(
+                jnp.sum(masks, axis=1, dtype=jnp.int32), axis
+            )
+            return masks, counts
 
-    return jax.jit(go)
+    return jax.jit(mkv_shard_divergence)
 
 
 def sharded_divergence_2d(
@@ -182,25 +187,27 @@ def _divergence_2d_program(mesh: Mesh, replica_axis: str, key_axis: str):
         out_specs=(P(replica_axis, key_axis), P(replica_axis)),
         check_vma=False,
     )
-    def go(dig, pres):
-        # Reference digests: global replica row 0, held by the first
-        # replica shard. Gather ONE row per replica shard — [Dr, n_local,
-        # 8] — and take shard 0's, NOT the full [R, n_local, 8] blocks
-        # (re-materializing those on every device would rebuild exactly
-        # the per-device footprint this 2-D program exists to avoid).
-        ref = jax.lax.all_gather(
-            dig[:1], replica_axis, axis=0, tiled=True
-        )[0]  # [n_local, 8]
-        ref_pres = jax.lax.all_gather(
-            pres[:1], replica_axis, axis=0, tiled=True
-        )[0]  # [n_local]
-        masks = divergence_vs_ref(dig, pres, ref[None], ref_pres[None])
-        counts = jax.lax.psum(
-            jnp.sum(masks, axis=1, dtype=jnp.int32), key_axis
-        )
-        return masks, counts
+    def mkv_shard_divergence_2d(dig, pres):
+        with jax.named_scope("mkv_shard_divergence_2d"):
+            # Reference digests: global replica row 0, held by the first
+            # replica shard. Gather ONE row per replica shard — [Dr,
+            # n_local, 8] — and take shard 0's, NOT the full [R, n_local,
+            # 8] blocks (re-materializing those on every device would
+            # rebuild exactly the per-device footprint this 2-D program
+            # exists to avoid).
+            ref = jax.lax.all_gather(
+                dig[:1], replica_axis, axis=0, tiled=True
+            )[0]  # [n_local, 8]
+            ref_pres = jax.lax.all_gather(
+                pres[:1], replica_axis, axis=0, tiled=True
+            )[0]  # [n_local]
+            masks = divergence_vs_ref(dig, pres, ref[None], ref_pres[None])
+            counts = jax.lax.psum(
+                jnp.sum(masks, axis=1, dtype=jnp.int32), key_axis
+            )
+            return masks, counts
 
-    return jax.jit(go)
+    return jax.jit(mkv_shard_divergence_2d)
 
 
 def make_anti_entropy_step(mesh: Mesh, axis: str = "key", pallas=None):
@@ -248,17 +255,22 @@ def _anti_entropy_program(mesh: Mesh, axis: str, pallas: bool):
         out_specs=(P(None), P(None, axis), P(None)),
         check_vma=False,
     )
-    def step(blk, nb, dig, pres):
-        _check_local_block(blk.shape[0])
-        leaves = hash_blocks(blk, nb)  # Pallas on TPU, scan elsewhere
-        local_root = _local_root(leaves)  # [1, 8]
-        roots = jax.lax.all_gather(local_root, axis, axis=0, tiled=True)  # [D, 8]
-        root = build_levels(roots)[-1][0]  # [8]
-        masks = divergence_masks(dig, pres)
-        counts = jax.lax.psum(jnp.sum(masks, axis=1, dtype=jnp.int32), axis)
-        return root, masks, counts
+    def mkv_shard_anti_entropy_step(blk, nb, dig, pres):
+        with jax.named_scope("mkv_shard_anti_entropy_step"):
+            _check_local_block(blk.shape[0])
+            leaves = hash_blocks(blk, nb)  # Pallas on TPU, scan elsewhere
+            local_root = _local_root(leaves)  # [1, 8]
+            roots = jax.lax.all_gather(
+                local_root, axis, axis=0, tiled=True
+            )  # [D, 8]
+            root = build_levels(roots)[-1][0]  # [8]
+            masks = divergence_masks(dig, pres)
+            counts = jax.lax.psum(
+                jnp.sum(masks, axis=1, dtype=jnp.int32), axis
+            )
+            return root, masks, counts
 
-    return jax.jit(step)
+    return jax.jit(mkv_shard_anti_entropy_step)
 
 
 def sharded_anti_entropy_step(
@@ -343,13 +355,15 @@ def _levels_body(mesh: Mesh, axis: str, capacity: int):
         out_specs=padded_level_specs(capacity, d, axis),
         check_vma=False,
     )
-    def go(block):  # [L, 8] local leaf slice
-        local = _reduce_padded(block)  # sizes L .. 1
-        roots = jax.lax.all_gather(local[-1], axis, axis=0, tiled=True)
-        top = _reduce_padded(roots)[1:]  # sizes D/2 .. 1 (empty when D=1)
-        return (*local, *top)
+    def mkv_shard_build_levels(block):  # [L, 8] local leaf slice
+        with jax.named_scope("mkv_shard_build_levels"):
+            local = _reduce_padded(block)  # sizes L .. 1
+            roots = jax.lax.all_gather(local[-1], axis, axis=0, tiled=True)
+            # Sizes D/2 .. 1 (empty when D=1).
+            top = _reduce_padded(roots)[1:]
+            return (*local, *top)
 
-    return go
+    return mkv_shard_build_levels
 
 
 @lru_cache(maxsize=None)
@@ -393,31 +407,36 @@ def sharded_scatter_program(
         out_specs=specs,
         check_vma=False,
     )
-    def go(*args):
-        levels, (idx, digests) = args[:n_local], args[n_local:]
-        from merklekv_tpu.ops.dispatch import hash_node_pairs as _pairs
+    def mkv_shard_scatter(*args):
+        with jax.named_scope("mkv_shard_scatter"):
+            levels, (idx, digests) = args[:n_local], args[n_local:]
+            from merklekv_tpu.ops.dispatch import hash_node_pairs as _pairs
 
-        new = digests[0]  # [kb, 8]
-        tgt = idx[0]  # [kb] local positions; pads already == scratch (L)
-        scratch = jnp.zeros((1, 8), jnp.uint32)
-        child = jnp.concatenate([levels[0], scratch]).at[tgt].set(new)
-        out = [child[:-1]]
-        cur = tgt
-        for j in range(1, n_local):
-            size = levels[j].shape[0]  # l >> j
-            # Parent path; pads carry through to each level's scratch slot.
-            cur = jnp.minimum(cur // 2, size)
-            # Children read from the UPDATED child level; a pad's children
-            # (2*size, 2*size+1) hit the scratch row / clamp out of range —
-            # garbage hashed into scratch, dropped below.
-            parents = _pairs(child[2 * cur], child[2 * cur + 1])
-            child = jnp.concatenate([levels[j], scratch]).at[cur].set(parents)
-            out.append(child[:-1])
-        roots = jax.lax.all_gather(out[-1], axis, axis=0, tiled=True)
-        top = _reduce_padded(roots)[1:]
-        return (*out, *top)
+            new = digests[0]  # [kb, 8]
+            # [kb] local positions; pads already == scratch (L).
+            tgt = idx[0]
+            scratch = jnp.zeros((1, 8), jnp.uint32)
+            child = jnp.concatenate([levels[0], scratch]).at[tgt].set(new)
+            out = [child[:-1]]
+            cur = tgt
+            for j in range(1, n_local):
+                size = levels[j].shape[0]  # l >> j
+                # Parent path; pads carry through to each level's scratch
+                # slot.
+                cur = jnp.minimum(cur // 2, size)
+                # Children read from the UPDATED child level; a pad's
+                # children (2*size, 2*size+1) hit the scratch row / clamp
+                # out of range — garbage hashed into scratch, dropped below.
+                parents = _pairs(child[2 * cur], child[2 * cur + 1])
+                child = jnp.concatenate([levels[j], scratch]).at[cur].set(
+                    parents
+                )
+                out.append(child[:-1])
+            roots = jax.lax.all_gather(out[-1], axis, axis=0, tiled=True)
+            top = _reduce_padded(roots)[1:]
+            return (*out, *top)
 
-    return jax.jit(go)
+    return jax.jit(mkv_shard_scatter)
 
 
 @lru_cache(maxsize=None)
@@ -434,12 +453,13 @@ def sharded_restructure_program(
     body = _levels_body(mesh, axis, c_new)
 
     @jax.jit
-    def go(old_leaves, gather_idx, fresh_pos, fresh):
-        safe = jnp.clip(gather_idx, 0, max(c_old - 1, 0))
-        base = jnp.where((gather_idx >= 0)[:, None], old_leaves[safe], 0)
-        if kb:
-            base = base.at[fresh_pos].set(fresh)
-        base = jax.lax.with_sharding_constraint(base, leaf_spec)
-        return body(base)
+    def mkv_shard_restructure(old_leaves, gather_idx, fresh_pos, fresh):
+        with jax.named_scope("mkv_shard_restructure"):
+            safe = jnp.clip(gather_idx, 0, max(c_old - 1, 0))
+            base = jnp.where((gather_idx >= 0)[:, None], old_leaves[safe], 0)
+            if kb:
+                base = base.at[fresh_pos].set(fresh)
+            base = jax.lax.with_sharding_constraint(base, leaf_spec)
+            return body(base)
 
-    return go
+    return mkv_shard_restructure

@@ -48,6 +48,7 @@ from merklekv_tpu.parallel.sharded_merkle import (
     sharded_restructure_program,
     sharded_scatter_program,
 )
+from merklekv_tpu.utils.tracing import span
 
 __all__ = ["ShardedDeviceMerkleState", "resolve_shard_count"]
 
@@ -176,7 +177,7 @@ class ShardedDeviceMerkleState(DeviceMerkleState):
         self.last_shard_rebuild_us = int(dt * 1e6)
         m = get_metrics()
         m.inc("device.shard_batches")
-        # Async-enqueue semantics, like the *_dispatch histograms: this is
+        # Async-enqueue semantics, like the device.scatter span: this is
         # trace+enqueue cost (queue pressure), not on-device execution.
         m.observe("device.shard_rebuild_dispatch", dt)
 
@@ -214,10 +215,11 @@ class ShardedDeviceMerkleState(DeviceMerkleState):
         d = self._n_shards
         l = self._capacity // d
         pos = self._positions([key for key, _ in items])
-        fresh = leaf_digests(
-            [key for key, _ in items], [v for _, v in items],
-            label=self._label("scatter"),
-        )
+        with span("device.leaf_hash", log=False):
+            fresh = leaf_digests(
+                [key for key, _ in items], [v for _, v in items],
+                label=self._label("scatter"),
+            )
         shard = pos // l
         local = pos % l
         counts = np.bincount(shard, minlength=d)
@@ -233,16 +235,15 @@ class ShardedDeviceMerkleState(DeviceMerkleState):
         digests[srt, offs] = fresh[order]
 
         n_local = _local_level_count(self._capacity, d)
-        t0 = time.perf_counter()
-        fn = sharded_scatter_program(
-            self._mesh, self._axis, self._capacity, kb, use_pallas()
-        )
-        self._levels = get_guard().call(
-            self._label("scatter"), fn, *self._levels[:n_local],
-            self._put_routed(idx), self._put_routed(digests),
-        )
+        with span("device.scatter", log=False):
+            fn = sharded_scatter_program(
+                self._mesh, self._axis, self._capacity, kb, use_pallas()
+            )
+            self._levels = get_guard().call(
+                self._label("scatter"), fn, *self._levels[:n_local],
+                self._put_routed(idx), self._put_routed(digests),
+            )
         self.incremental_batches += 1
         m = get_metrics()
         m.inc("device.scatter_keys", k)
         m.inc("device.scatter_bytes", int(digests.nbytes + idx.nbytes))
-        m.observe("device.scatter_dispatch", time.perf_counter() - t0)

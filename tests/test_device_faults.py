@@ -129,8 +129,14 @@ def test_guard_nested_call_runs_inline_no_false_hang():
     """A guarded call issued FROM the guard worker (query-path gather
     triggering a staged flush) must run inline, not deadlock into a
     false hang against the busy single worker."""
+    from merklekv_tpu.obs.metrics import get_metrics
+
     g = DispatchGuard(deadline_ms=500, policy=FAST)
+    before = get_metrics().snapshot()["counters"].get("device.dispatches", 0)
     assert g.run("outer", lambda: g.run("inner", lambda: 5)) == 5
+    # The inner call is part of the outer dispatch: counted once.
+    after = get_metrics().snapshot()["counters"]["device.dispatches"]
+    assert after - before == 1
 
 
 def test_guard_first_compile_is_not_a_hang():
@@ -143,6 +149,12 @@ def test_guard_first_compile_is_not_a_hang():
 
     from merklekv_tpu.ops.sha256 import sha256_blocks
 
+    from merklekv_tpu.obs.metrics import get_metrics
+
+    def compiles() -> int:
+        spans = get_metrics().snapshot()["spans"]
+        return spans.get("device.compile", {}).get("count", 0)
+
     # CPU: ~0.4 s to compile, ~1 ms to run.
     g = DispatchGuard(deadline_ms=150, policy=FAST)
     blocks = np.zeros((4, 3, 16), np.uint32)
@@ -150,11 +162,13 @@ def test_guard_first_compile_is_not_a_hang():
     with pytest.raises(DispatchHungError):
         g.run("build", lambda: jax.jit(sha256_blocks)(blocks, nb))
     prog = jax.jit(sha256_blocks)
+    c0 = compiles()
     out = np.asarray(g.call("build", prog, blocks, nb))
     np.testing.assert_array_equal(out, np.asarray(sha256_blocks(blocks, nb)))
     assert g.compiled("build", prog, blocks, nb) is g.compiled(
         "build", prog, blocks, nb
     )
+    assert compiles() - c0 == 1  # a cache hit records no compile span
     with pytest.raises(DeviceDispatchError):
         g.compiled("build", prog, blocks, nb[:3])  # shapes do not trace
 

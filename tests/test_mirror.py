@@ -199,3 +199,56 @@ def test_mirror_converges_despite_event_payload_staleness():
         mirror.close()
     finally:
         eng.close()
+
+
+def test_pump_publish_records_its_spans_and_dispatches(monkeypatch):
+    """One pump publish of a value update records each layer's span once
+    — drain, leaf hash, scatter, root walk, the staged change's age — and
+    counts every guard call it made in ``device.dispatches``."""
+    from merklekv_tpu.device.guard import get_guard
+    from merklekv_tpu.obs.metrics import get_metrics
+
+    eng = NativeEngine("mem")
+    mirror = None
+    try:
+        for i in range(8):
+            eng.set(b"pk%d" % i, b"v0")
+        mirror = DeviceTreeMirror(eng, scrub_interval_s=0)
+        mirror.root_hex()  # warm build + first publish, outside the pump
+        guard = get_guard()
+        run = guard.run
+        calls = []
+
+        def counting_run(label, fn):
+            calls.append(label)
+            return run(label, fn)
+
+        monkeypatch.setattr(guard, "run", counting_run)
+        m = get_metrics()
+        before = m.snapshot()
+        eng.set(b"pk3", b"v1")
+        mirror.apply_batch([(b"pk3", b"v1")])
+        deadline = time.time() + 60
+        while (
+            m.snapshot()["counters"].get("device.pump_batches", 0)
+            == before["counters"].get("device.pump_batches", 0)
+        ):
+            assert time.time() < deadline, "the pump never published"
+            time.sleep(0.01)
+        after = m.snapshot()
+        names = ("pump.drain", "device.leaf_hash", "device.scatter",
+                 "device.root_walk", "pump.staged_age")
+        got = {
+            n: after["spans"].get(n, {}).get("count", 0)
+            - before["spans"].get(n, {}).get("count", 0)
+            for n in names
+        }
+        assert got == dict.fromkeys(names, 1)
+        dispatched = (after["counters"]["device.dispatches"]
+                      - before["counters"].get("device.dispatches", 0))
+        assert len(calls) >= 3 and dispatched == len(calls)
+        assert mirror.published_root_hex() == eng.merkle_root().hex()
+    finally:
+        if mirror is not None:
+            mirror.close()
+        eng.close()

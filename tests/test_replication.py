@@ -537,6 +537,13 @@ def test_frame_of_k_writes_is_one_mirror_dispatch(pair):
     assert wait_for(
         lambda: n2.cluster.device_root_hex() is not None, timeout=90
     )
+    # Settle n2's tree before the baseline: every dk* write published and
+    # the device root equal to the engine root, so the counts below see
+    # the frame's dispatch alone.
+    assert wait_for(
+        lambda: n2.cluster.device_root_hex(force=True)
+        == n2.engine.merkle_root().hex()
+    )
     st = n2.cluster._mirror.state
     base_inc = st.incremental_batches
     base_struct = st.structural_batches
