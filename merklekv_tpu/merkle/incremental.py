@@ -21,9 +21,10 @@ Representation: a FULL binary tree at capacity C = 2^d (slots >= n hold a
 zero sentinel). The reference tree pairs only live nodes and promotes odd
 tails, so its levels differ from the padded tree's — but only on the right
 spine: by induction, reference level l equals padded level l at every
-position except the last. ``_ref_root`` therefore recovers the bit-exact
-reference root in one O(log C) walk that carries the corrected last node
-("promotion chain") and reads one padded node per level.
+position except the last. ``mkv_root_walk`` therefore recovers the bit-exact
+reference root from one padded node per level and a chain of log2(C) node
+hashes that carries the corrected last node ("promotion chain"), each hash
+backend-dispatched like every other tree program's.
 
 Host memory: only the sorted key array is kept (values are never stored —
 fresh digests are computed from the (key, value) pairs each batch carries),
@@ -39,6 +40,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from merklekv_tpu.device.guard import get_guard
 from merklekv_tpu.merkle.jax_engine import leaf_digests
@@ -48,11 +50,7 @@ from merklekv_tpu.ops.dispatch import (
     hash_node_pairs,
     use_pallas,
 )
-from merklekv_tpu.ops.sha256 import (
-    digest_to_bytes,
-    digests_to_bytes,
-    sha256_node_pairs,
-)
+from merklekv_tpu.ops.sha256 import digest_to_bytes, digests_to_bytes
 from merklekv_tpu.utils.tracing import span
 
 __all__ = ["DeviceMerkleState"]
@@ -160,9 +158,26 @@ def _restructure_fn(c_old: int, c_new: int, kb: int, pallas: bool):
 
 
 @lru_cache(maxsize=None)
-def _ref_root_fn(capacity: int):
+def _ref_root_fn(capacity: int, pallas: bool, mesh=None):
     """Compiled promotion-chain walk: padded levels + live count n -> the
-    reference odd-promotion root over the first n leaves."""
+    reference odd-promotion root over the first n leaves.
+
+    The level sizes, and so the one padded node each level may combine,
+    depend on n alone: the walk reads those log2(C) rows first, then runs
+    the chain of node hashes on [1, 8] state, each through the backend
+    dispatch (the Pallas node kernel on TPU). Every level hashes and a
+    select keeps the result or the promoted tail, so n stays traced: one
+    compile per capacity, whatever the live count.
+
+    ``mesh`` is the mesh a sharded tree's levels live on. A Pallas kernel
+    cannot be partitioned automatically, so there each hash runs under
+    ``shard_map`` on every device's replicated copy of its [1, 8] rows."""
+    node_hash = hash_node_pairs
+    if pallas and mesh is not None:
+        node_hash = jax.shard_map(
+            hash_node_pairs, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+            check_vma=False,
+        )
 
     @jax.jit
     def mkv_root_walk(levels: tuple, n: jax.Array):
@@ -171,21 +186,22 @@ def _ref_root_fn(capacity: int):
             last = jax.lax.dynamic_index_in_dim(
                 levels[0], jnp.maximum(m - 1, 0), axis=0, keepdims=False
             )
+            sizes, prevs = [], []
             for lvl in range(1, len(levels)):
-                odd = (m % 2) == 1
+                sizes.append(m)
                 # Even m: reference's next last = H(level[m-2], last).
                 # Position m-2 of the reference level equals the padded
                 # level (only the last position can differ).
-                prev = jax.lax.dynamic_index_in_dim(
+                prevs.append(jax.lax.dynamic_index_in_dim(
                     levels[lvl - 1], jnp.maximum(m - 2, 0), axis=0,
                     keepdims=False,
-                )
-                combined = sha256_node_pairs(prev[None], last[None])[0]
-                # Odd m: the tail is promoted unchanged. m == 1: stay at
-                # root.
-                new_last = jnp.where(odd, last, combined)
-                last = jnp.where(m <= 1, last, new_last)
-                m = jnp.where(m <= 1, m, (m + 1) // 2)
+                ))
+                m = (m + 1) // 2
+            for m, prev in zip(sizes, prevs):
+                combined = node_hash(prev[None], last[None])[0]
+                # Odd m (m == 1 at and above the root): the tail is
+                # promoted unchanged.
+                last = jnp.where(m % 2 == 1, last, combined)
             return last
 
     return mkv_root_walk
@@ -545,8 +561,10 @@ class DeviceMerkleState:
             self._flush()
         if not len(self._keys) or self._levels is None:
             return None
+        mesh = None if self._sharding is None else self._sharding.mesh
+        fn = _ref_root_fn(self._capacity, use_pallas(), mesh)
         return digest_to_bytes(get_guard().call(
-            self._label("root"), _ref_root_fn(self._capacity), self._levels,
+            self._label("root"), fn, self._levels,
             jnp.int32(len(self._keys)), fetch=True,
         ))
 

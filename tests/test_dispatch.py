@@ -41,7 +41,8 @@ def test_dispatch_mode_selection(monkeypatch):
 
 def test_production_paths_route_through_dispatch(monkeypatch):
     """With the backend forced to "pallas", the incremental tree's build,
-    scatter, and restructure programs must reach the Pallas entry points.
+    scatter, restructure and root-walk programs must reach the Pallas entry
+    points.
     The spy delegates to the scan math so the roots stay correct on CPU."""
     import merklekv_tpu.ops.sha256_pallas as sp
     from merklekv_tpu.merkle import incremental, jax_engine
@@ -76,6 +77,7 @@ def test_production_paths_route_through_dispatch(monkeypatch):
     incremental._build_fn.cache_clear()
     incremental._scatter_fn.cache_clear()
     incremental._restructure_fn.cache_clear()
+    incremental._ref_root_fn.cache_clear()
     jax_engine._leaf_fn.cache_clear()
 
     items = {b"rk%03d" % i: b"rv%d" % i for i in range(21)}
@@ -102,6 +104,7 @@ def test_production_paths_route_through_dispatch(monkeypatch):
     incremental._build_fn.cache_clear()
     incremental._scatter_fn.cache_clear()
     incremental._restructure_fn.cache_clear()
+    incremental._ref_root_fn.cache_clear()
     jax_engine._leaf_fn.cache_clear()
 
 

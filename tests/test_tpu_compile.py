@@ -95,3 +95,41 @@ def test_sharded_diff_compiles_for_v5e_2x2(topo):
     assert "all-reduce" in text
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert per_device < r * n * 8 * 4  # each chip holds a quarter
+
+
+def _walk_levels(c, sharding_of):
+    return tuple(
+        _spec((c >> j, 8), jnp.uint32, sharding_of(c >> j))
+        for j in range(c.bit_length())
+    )
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_root_walk_runs_the_node_kernel_on_v5e(topo, monkeypatch, shards):
+    """The promotion-chain walk at the benchmark's capacity (2^19) hashes
+    through the Pallas node kernel, one call per level and no loop; over a
+    2x2 mesh, with the levels sharded, the kernel runs under shard_map."""
+    from merklekv_tpu.merkle import incremental
+    from merklekv_tpu.ops import sha256_pallas as sp
+
+    # The dispatch reads the backend of this (CPU) process: steer it to
+    # the chip's branch.
+    monkeypatch.setenv("MKV_SHA256_BACKEND", "pallas")
+    monkeypatch.setattr(sp, "pallas_supported", lambda: True)
+    c = 1 << 19
+    if shards == 1:
+        mesh = None
+        one = SingleDeviceSharding(topo.devices[0])
+        levels = _walk_levels(c, lambda rows: one)
+        n = _spec((), jnp.int32, one)
+    else:
+        mesh = Mesh(np.array(topo.devices[:shards]), ("key",))
+        levels = _walk_levels(c, lambda rows: NamedSharding(
+            mesh, P("key", None) if rows >= shards else P()))
+        n = _spec((), jnp.int32, NamedSharding(mesh, P()))
+    text = (
+        incremental._ref_root_fn.__wrapped__(c, True, mesh)
+        .lower(levels, n).compile().as_text()
+    )
+    assert text.count("tpu_custom_call") == c.bit_length() - 1
+    assert " while(" not in text

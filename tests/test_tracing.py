@@ -156,7 +156,7 @@ def _lowered_programs():
         "mkv_restructure": lambda: incremental._restructure_fn(
             c, c, 16, False).lower(sds(c, 8), sds(c, dtype=i32),
                                    sds(16, dtype=i32), sds(16, 8)),
-        "mkv_root_walk": lambda: incremental._ref_root_fn(c)
+        "mkv_root_walk": lambda: incremental._ref_root_fn(c, False)
         .lower(levels, sds(dtype=i32)),
         "mkv_level_gather": lambda: incremental.mkv_level_gather
         .lower(sds(c, 8), sds(dtype=i32), 4),
