@@ -61,6 +61,31 @@ def scatter_count(ctx) -> int:
     return node.metrics().get("device.scatter_keys", 0)
 
 
+def warm_scatter(ctx) -> int:
+    """Every scatter bucket of every mirror's capacity, compiled ahead
+    through the dispatch guard (nothing runs); returns how many."""
+    import jax.numpy as jnp
+
+    from merklekv_tpu.device.guard import get_guard
+    from merklekv_tpu.merkle import incremental
+    from merklekv_tpu.ops.dispatch import use_pallas
+
+    guard, asked = get_guard(), 0
+    capacities = {}
+    for node in ctx.cluster.nodes.values():
+        state = node.served.node._mirror._state
+        capacities[state._capacity] = state._levels
+    for capacity, levels in capacities.items():
+        kb = 16
+        while kb <= capacity:
+            guard.compiled("benchmark.warm", incremental._scatter_fn(
+                capacity, kb, use_pallas()), levels,
+                jnp.zeros(kb, jnp.int32), jnp.zeros((kb, 8), jnp.uint32))
+            asked += 1
+            kb *= 2
+    return asked
+
+
 def warm_programs(ctx, extra: dict) -> int:
     """Make ready in set-up every leaf-hash and scatter program a pump can
     reach while the keyspace keeps its size, whatever batch the traffic
@@ -75,28 +100,11 @@ def warm_programs(ctx, extra: dict) -> int:
     compile-ahead (nothing runs), the leaf hashes by hashing that many of
     the dataset's records (a pure function). The tree is not touched.
     ``extra`` holds the keys written beside the dataset (one row each)."""
-    import jax.numpy as jnp
-
-    from merklekv_tpu.device.guard import get_guard
-    from merklekv_tpu.merkle import incremental
     from merklekv_tpu.merkle.jax_engine import leaf_digests
     from merklekv_tpu.merkle.packing import GROUP_BLOCKS
-    from merklekv_tpu.ops.dispatch import use_pallas
 
-    ds, guard = ctx.ds, get_guard()
-    asked = 0
-    capacities = {}
-    for node in ctx.cluster.nodes.values():
-        state = node.served.node._mirror._state
-        capacities[state._capacity] = state._levels
-    for capacity, levels in capacities.items():
-        kb = 16
-        while kb <= capacity:
-            guard.compiled("benchmark.warm", incremental._scatter_fn(
-                capacity, kb, use_pallas()), levels,
-                jnp.zeros(kb, jnp.int32), jnp.zeros((kb, 8), jnp.uint32))
-            asked += 1
-            kb *= 2
+    ds = ctx.ds
+    asked = warm_scatter(ctx)
     # Leaf groups: records of one padded width (the power of two at or
     # above their SHA-256 block count, at their loaded size) hash together,
     # at most GROUP_BLOCKS blocks a group.

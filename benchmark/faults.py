@@ -15,7 +15,15 @@ comparison with the reference must then come out false.
   under node a's server, so its GETs answer the wrong bytes (an answer
   altered where it is produced).
 - ``half_batch``: the device tree applies only the first half of each
-  scatter batch (half of the batch left out).
+  scatter batch (half of the batch left out). The mirrors' integrity scrub
+  is turned off for the run: it would find the dropped leaves and rebuild
+  the tree from the engine before the check.
+- ``no_rejoin`` (the control of the repair cells): replica b's
+  ``REPLICATE enable`` and ``SYNC`` do nothing, so b never rejoins and is
+  never repaired.
+- ``lost_repair``: after each SYNC reply one key the SYNC repaired is
+  dropped from the initiator's engine (an answer altered where it is
+  produced).
 """
 
 from __future__ import annotations
@@ -62,6 +70,34 @@ class HalfBatch:
             return orig(self, items[: (len(items) + 1) // 2])
 
         cls._update_in_place = half
+        from merklekv_tpu.cluster.mirror import DeviceTreeMirror
+
+        DeviceTreeMirror._maybe_scrub = lambda self: None
+
+
+class NoRejoin:
+    def before_window(self, ctx) -> None:
+        from merklekv_tpu.cluster.node import ClusterNode
+        from merklekv_tpu.cluster.sync import SyncManager
+
+        ClusterNode._enable_replication = lambda self: None
+        SyncManager.sync_once = lambda self, *a, **kw: None
+
+
+class LostRepair:
+    def before_window(self, ctx) -> None:
+        from merklekv_tpu.cluster.sync import SyncManager
+
+        orig = SyncManager.sync_once
+
+        def lossy(manager, *a, **kw):
+            report = orig(manager, *a, **kw)
+            # The first key of the episode that was diverged.
+            manager._engine.delete_quiet(
+                ctx.ds.keys[int(ctx.episode["distinct"][0])])
+            return report
+
+        SyncManager.sync_once = lossy
 
 
 FAULTS = {
@@ -69,4 +105,6 @@ FAULTS = {
     "lost_write": LostWrite,
     "altered_answer": AlteredAnswer,
     "half_batch": HalfBatch,
+    "no_rejoin": NoRejoin,
+    "lost_repair": LostRepair,
 }

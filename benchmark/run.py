@@ -214,7 +214,9 @@ def run_cell(args, require_tpu: bool = True, faults=None,
         ctx.readings.update(setup_programs=count_0, setup_cache_hits=hits_0)
         done = threading.Event()
         tracer = None
-        if ctx.trace:
+        # A driver that traces a span of its own choosing (one repair
+        # episode) says so; the others get a fixed slice of the window.
+        if ctx.trace and not getattr(driver, "TRACES_ITSELF", False):
             tracer = threading.Thread(target=_tracer,
                                       args=(ctx, trace_dir, done))
             tracer.start()

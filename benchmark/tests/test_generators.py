@@ -94,3 +94,36 @@ def test_open_loop_versions_follow_schedule_order():
         else:
             assert v == last[k]
     assert np.array_equal(last, versions)
+
+
+def _gev_closed_form(p, loc, scale, shape):
+    """GEV quantile from its CDF exp(-(1 + shape (x - loc) / scale)^(-1 /
+    shape))."""
+    return loc + scale * ((-np.log(p)) ** (-shape) - 1.0) / shape
+
+
+def _gpareto_closed_form(p, loc, scale, shape):
+    """Generalized Pareto quantile from its CDF 1 - (1 + shape (x - loc) /
+    scale)^(-1 / shape)."""
+    return loc + scale * ((1.0 - p) ** (-shape) - 1.0) / shape
+
+
+def test_etc_sample_quantiles_match_the_closed_forms():
+    """The sizes a dataset draws (keys once, values at every write) follow
+    the published GEV and generalized Pareto at their quantiles."""
+    cfg = dict(ETC, keycount=200_000,
+               values=dict(ETC["values"], max_bytes=1_048_000))
+    ds = data.Dataset(cfg, 2**31 + 99)
+    probs = np.array([0.05, 0.25, 0.5, 0.75, 0.95, 0.99])
+    klen = np.array([len(k) for k in ds.keys], np.float64)
+    want_k = _gev_closed_form(probs, 30.7984, 8.20449, 0.078688)
+    got_k = np.quantile(klen, probs)
+    # Sizes are whole bytes: rounding moves a quantile by half a byte.
+    assert np.all(np.abs(got_k - want_k) < 1.0), (got_k, want_k)
+    idx = np.arange(ds.n)
+    for ver in (0, 1, 7):
+        sizes = ds._size(idx, np.full(ds.n, ver, np.int64))
+        want_v = _gpareto_closed_form(probs, 15.0, 214.476, 0.348238)
+        got_v = np.quantile(sizes.astype(np.float64), probs)
+        assert np.all(np.abs(got_v - want_v) <= 0.02 * want_v + 1.0), \
+            (ver, got_v, want_v)
