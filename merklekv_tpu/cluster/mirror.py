@@ -942,6 +942,18 @@ class DeviceTreeMirror:
                 self._pub_snapshot = (root, self._published_version)
             return root
 
+    def ready_restructure_programs(self, max_leaves: int) -> int:
+        """Compile ahead every restructure program the pump can reach while
+        the built tree grows to ``max_leaves`` keys
+        (``DeviceMerkleState.ready_restructure_programs``); nothing runs.
+        Compiles outside the mirror lock, so staging goes on meanwhile.
+        Returns how many programs were asked for: 0 while warming and on
+        the CPU rung."""
+        with self._mu:
+            st = None if self._closed else self._state
+        ready = getattr(st, "ready_restructure_programs", None)
+        return 0 if ready is None else ready(max_leaves)
+
     def level_nodes(self, level: int, lo: int, hi: int):
         """TREELEVEL slice from the last-published device tree: reference-
         level ``(idx, digest)`` rows plus the leaf count, or None while the

@@ -917,6 +917,17 @@ class ClusterNode:
             lambda m: m.published_root_hex(), force=force
         )
 
+    def ready_restructure_programs(self, max_leaves: int) -> int:
+        """Compile ahead every restructure program the device mirror's pump
+        can reach while its tree grows to ``max_leaves`` keys, so the first
+        inserts that shift or grow the tree make no program ready on the
+        serving path. Returns how many were asked for (0 without a built
+        mirror)."""
+        with self._rep_mu:
+            mirror = self._mirror
+        return (0 if mirror is None
+                else mirror.ready_restructure_programs(max_leaves))
+
     @property
     def health(self):
         return self._health

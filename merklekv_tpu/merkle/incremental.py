@@ -33,6 +33,7 @@ so a 10M-key tree costs the host one object array, not a value map.
 
 from __future__ import annotations
 
+import time
 from functools import lru_cache, partial
 from typing import Iterable, Optional, Sequence
 
@@ -63,6 +64,20 @@ def _next_pow2(n: int) -> int:
 def _bucket(k: int) -> int:
     """Round a batch size up so one compiled program serves many sizes."""
     return _next_pow2(max(k, 16))
+
+
+# A restructure pads its fresh digests to 4096 rows, or to the whole new
+# capacity for a larger batch. Each restructure program re-reduces the
+# whole tree, so scattering a few thousand padded rows costs nothing beside
+# it, while every bucket is one more full-tree program to compile ahead.
+_RESTRUCTURE_BUCKET = 4096
+
+
+def _restructure_buckets(c_new: int) -> list[int]:
+    """Every fresh-digest bucket a restructure into capacity ``c_new`` uses."""
+    if c_new <= _RESTRUCTURE_BUCKET:
+        return [c_new]
+    return [_RESTRUCTURE_BUCKET, c_new]
 
 
 def _reduce_levels(leaves: jax.Array) -> tuple:
@@ -366,20 +381,32 @@ class DeviceMerkleState:
             raise
 
     def _flush_batch(self, pending: dict[bytes, Optional[bytes]]) -> None:
-        # One membership pass (O(1) dict probes) classifies the whole batch.
-        keys = sorted(pending)
-        idx = self._index
-        deletes = [k for k in keys if k in idx and pending[k] is None]
-        inserts = [k for k in keys if k not in idx and pending[k] is not None]
-        upserts = {k: v for k, v in pending.items() if v is not None}
+        t0 = time.perf_counter()
+        with span("device.key_index", log=False):
+            # One membership pass (O(1) dict probes) classifies the whole
+            # batch; a structural batch also plans its new key array here.
+            keys = sorted(pending)
+            idx = self._index
+            deletes = [k for k in keys if k in idx and pending[k] is None]
+            inserts = [
+                k for k in keys if k not in idx and pending[k] is not None
+            ]
+            upserts = {k: v for k, v in pending.items() if v is not None}
+            structural = bool(deletes or inserts)
+            if structural:
+                plan = self._plan_restructure(deletes, inserts)
 
-        if not deletes and not inserts:
+        if not structural:
             updates = sorted(upserts.items())
             if updates and self._levels is not None:
                 self._update_in_place(updates)
             return
-        with span("device.restructure", log=False):
-            self._restructure(deletes, upserts, inserts)
+        self._restructure(*plan, upserts)
+        # Not a ``with`` block: a batch is known to be structural only
+        # after its lookup, which this span includes.
+        get_metrics().observe_span(
+            "device.restructure", time.perf_counter() - t0
+        )
 
     def _update_in_place(self, items: list[tuple[bytes, bytes]]) -> None:
         k = len(items)
@@ -444,16 +471,15 @@ class DeviceMerkleState:
         self._capacity = c
         self.full_rebuilds += 1
 
-    def _restructure(
-        self,
-        deletes: list[bytes],
-        upserts: dict[bytes, Optional[bytes]],
-        inserts: list[bytes],
-    ) -> None:
+    def _plan_restructure(
+        self, deletes: list[bytes], inserts: list[bytes]
+    ) -> tuple[np.ndarray, dict[bytes, int], np.ndarray]:
+        """Host plan of a structural batch: pure index arithmetic, no
+        hashing of survivors. Returns the new sorted key array, its
+        key -> position index, and the source slot in the old leaf level of
+        each new slot (-1: fresh)."""
         old = self._keys
         n_old = len(old)
-
-        # Host plan: pure index arithmetic, no hashing of survivors.
         del_pos = self._positions(deletes)
         survivors = np.delete(old, del_pos) if len(del_pos) else old
         surv_src = (
@@ -469,6 +495,15 @@ class DeviceMerkleState:
         else:
             new_keys = survivors
             gather = surv_src
+        return new_keys, {k: i for i, k in enumerate(new_keys)}, gather
+
+    def _restructure(
+        self,
+        new_keys: np.ndarray,
+        new_index: dict[bytes, int],
+        gather: np.ndarray,
+        upserts: dict[bytes, Optional[bytes]],
+    ) -> None:
         n_new = len(new_keys)
         if n_new == 0:
             self._set_keys(np.empty(0, dtype=object))
@@ -490,12 +525,11 @@ class DeviceMerkleState:
         # Fresh digests: every upsert (update of a survivor or insert).
         fresh_items = sorted(upserts.items())
         k = len(fresh_items)
-        kb = _bucket(k) if k else 0
+        # k <= n_new <= c_new, so the last bucket always fits.
+        kb = next(b for b in _restructure_buckets(c_new) if b >= k) if k else 0
         if k:
-            fresh_keys = np.array([key for key, _ in fresh_items],
-                                  dtype=object)
             fresh_pos = np.empty(kb, np.int32)
-            fresh_pos[:k] = np.searchsorted(new_keys, fresh_keys)
+            fresh_pos[:k] = [new_index[key] for key, _ in fresh_items]
             fresh_pos[k:] = fresh_pos[0]
 
             # Guarded like the build path: the fresh-digest leaf hashing
@@ -516,23 +550,73 @@ class DeviceMerkleState:
         self._levels = self._dispatch_restructure(
             gather_padded, fresh_pos, fresh, kb, c_new
         )
-        self._set_keys(new_keys)
+        self._keys, self._index = new_keys, new_index
         self._capacity = c_new
         self.structural_batches += 1
         m = get_metrics()
+        m.inc("device.structural_batches")
         m.inc("device.restructure_keys", k)
         m.inc("device.restructure_bytes",
               int(gather_padded.nbytes + fresh_pos.nbytes + k * 32))
 
+    def ready_restructure_programs(self, max_leaves: int) -> int:
+        """Compile ahead, through the dispatch guard (nothing runs), every
+        program a structural flush can reach while the tree grows from its
+        capacity C up to the capacity that holds ``max_leaves`` keys: the
+        restructure from each such capacity into itself and into each
+        larger one, at every fresh-digest bucket, and the root walk at each
+        capacity above C. Returns how many programs were asked for (0 while
+        the tree is empty)."""
+        if self._levels is None:
+            return 0
+        guard, c, asked = get_guard(), self._capacity, 0
+        top = self._capacity_for(max_leaves)
+        mesh = None if self._sharding is None else self._sharding.mesh
+        # Leaf level a restructure starts from, by capacity: the built one,
+        # then each grown one as the shape (and sharding) its program gives.
+        leaves = {c: self._levels[0]}
+        c_new = c
+        while c_new <= max(c, top):
+            gather = self._put(np.zeros(c_new, np.int32), one_d=True)
+            for c_old in sorted(leaves) + [c_new] * (c_new not in leaves):
+                for kb in _restructure_buckets(c_new):
+                    exe = guard.compiled(
+                        self._label("restructure"),
+                        self._restructure_program(c_old, c_new, kb),
+                        leaves[c_old], gather,
+                        jnp.zeros(kb, jnp.int32),
+                        jnp.zeros((kb, 8), jnp.uint32),
+                    )
+                    asked += 1
+                if c_new not in leaves:
+                    grown = tuple(
+                        jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh)
+                        for s, sh in zip(exe.out_info, exe.output_shardings)
+                    )
+                    leaves[c_new] = grown[0]
+                    guard.compiled(
+                        self._label("root"),
+                        _ref_root_fn(c_new, use_pallas(), mesh),
+                        grown, jnp.int32(1),
+                    )
+                    asked += 1
+            c_new *= 2
+        return asked
+
     # ------------------------------------------------- device dispatch seam
     # The host planning above (classification, permutation index arithmetic,
-    # packing) is backend-agnostic; only these two hooks touch a compiled
+    # packing) is backend-agnostic; only these hooks touch a compiled
     # device program. ShardedDeviceMerkleState (parallel/sharded_state.py)
     # overrides them with explicit shard_map SPMD programs.
     def _dispatch_build(self, padded: np.ndarray) -> tuple:
         """Capacity-padded [C, 8] leaf digests -> every padded level."""
         fn = _build_fn(len(padded), use_pallas())
         return get_guard().call(self._label("build"), fn, self._put(padded))
+
+    def _restructure_program(self, c_old: int, c_new: int, kb: int):
+        """The jitted gather + scatter + full reduction for one shape
+        change (capacity ``c_old`` -> ``c_new``, ``kb`` fresh digests)."""
+        return _restructure_fn(c_old, c_new, kb, use_pallas())
 
     def _dispatch_restructure(
         self,
@@ -544,7 +628,7 @@ class DeviceMerkleState:
     ) -> tuple:
         """Gather survivors into shifted slots + scatter fresh digests +
         full re-reduction (``self._capacity`` still holds the OLD C)."""
-        fn = _restructure_fn(self._capacity, c_new, kb, use_pallas())
+        fn = self._restructure_program(self._capacity, c_new, kb)
         return get_guard().call(
             self._label("restructure"), fn, self._levels[0],
             self._put(gather_padded, one_d=True), jnp.asarray(fresh_pos),

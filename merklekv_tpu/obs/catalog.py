@@ -142,6 +142,9 @@ CATALOG: dict[str, tuple[str, str]] = {
         "counter", "Keys updated via incremental device scatter."),
     "device.scatter_bytes": (
         "counter", "Bytes transferred by device scatter batches."),
+    "device.structural_batches": (
+        "counter", "Structural (insert/delete) device batches: the key "
+        "array shifted and the whole tree re-reduced."),
     "device.restructure_keys": (
         "counter", "Keys in structural (insert/delete) device batches."),
     "device.restructure_bytes": (

@@ -190,12 +190,15 @@ class ShardedDeviceMerkleState(DeviceMerkleState):
         self._record_rebuild(t0)
         return levels
 
+    def _restructure_program(self, c_old: int, c_new: int, kb: int):
+        return sharded_restructure_program(
+            self._mesh, self._axis, c_old, c_new, kb, use_pallas()
+        )
+
     def _dispatch_restructure(
         self, gather_padded, fresh_pos, fresh, kb: int, c_new: int
     ) -> tuple:
-        fn = sharded_restructure_program(
-            self._mesh, self._axis, self._capacity, c_new, kb, use_pallas()
-        )
+        fn = self._restructure_program(self._capacity, c_new, kb)
         t0 = time.perf_counter()
         levels = get_guard().call(
             self._label("restructure"), fn, self._levels[0],

@@ -133,3 +133,29 @@ def test_root_walk_runs_the_node_kernel_on_v5e(topo, monkeypatch, shards):
     )
     assert text.count("tpu_custom_call") == c.bit_length() - 1
     assert " while(" not in text
+
+
+@pytest.mark.parametrize("grow", [1, 2])
+def test_restructure_compiles_for_v5e_at_the_benchmark_capacity(
+        one_chip, monkeypatch, grow):
+    """A structural batch's program at the benchmark's capacity (2^19),
+    into the same capacity and into the doubled one, at the smallest
+    fresh-digest bucket: the whole tree re-reduces through the Pallas node
+    level kernel, one call per level."""
+    from merklekv_tpu.merkle import incremental
+    from merklekv_tpu.ops import sha256_pallas as sp
+
+    monkeypatch.setenv("MKV_SHA256_BACKEND", "pallas")
+    monkeypatch.setattr(sp, "pallas_supported", lambda: True)
+    c = 1 << 19
+    c_new = grow * c
+    kb = incremental._restructure_buckets(c_new)[0]
+    text = (
+        incremental._restructure_fn.__wrapped__(c, c_new, kb, True)
+        .lower(_spec((c, 8), jnp.uint32, one_chip),
+               _spec((c_new,), jnp.int32, one_chip),
+               _spec((kb,), jnp.int32, one_chip),
+               _spec((kb, 8), jnp.uint32, one_chip))
+        .compile().as_text()
+    )
+    assert text.count("tpu_custom_call") == c_new.bit_length() - 1
